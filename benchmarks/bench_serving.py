@@ -382,15 +382,24 @@ def _prefix_rows(rec: Dict) -> List[str]:
 
 
 # ---------------------------------------------------------------------------
-# chaos smoke: launch/dryrun --serve-chaos (subprocess: the forced
-# 8-device host platform must not leak into this process)
+# chaos smoke: launch/dryrun --serve-chaos (a CPU-rehearsal subprocess:
+# the forced 8-device host platform must not leak into this process)
 # ---------------------------------------------------------------------------
 
 
-def _chaos_smoke() -> Dict:
+def _cpu_child_env() -> Dict[str, str]:
+    """Environment of the CPU-rehearsal children below. They start after
+    this process has imported JAX, which holds any accelerator, so they
+    are pinned to virtual CPU devices (``JAX_PLATFORMS=cpu``) and never
+    contend for the chip."""
     env = dict(os.environ, PYTHONPATH=_SRC + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
+               + os.environ.get("PYTHONPATH", ""), JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
+    return env
+
+
+def _chaos_smoke() -> Dict:
+    env = _cpu_child_env()
     try:
         out = subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun", "--serve-chaos"],
@@ -417,8 +426,8 @@ def _chaos_rows(rec: Dict) -> List[str]:
 
 
 # ---------------------------------------------------------------------------
-# 1-host vs simulated 8-device mesh (subprocess: forced device count must
-# not leak into the calling process)
+# 1-host vs simulated 8-device mesh (a CPU-rehearsal subprocess: the
+# forced device count must not leak into the calling process)
 # ---------------------------------------------------------------------------
 
 _MESH_SCRIPT = r"""
@@ -469,9 +478,7 @@ print("MESHJSON " + json.dumps({
 
 
 def _bench_mesh() -> Dict:
-    env = dict(os.environ, PYTHONPATH=_SRC + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_child_env()
     try:
         out = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], env=env,
                              capture_output=True, text=True, timeout=900)

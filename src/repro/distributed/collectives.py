@@ -24,29 +24,6 @@ from jax.sharding import PartitionSpec as P
 from repro.optim import compression as C
 
 
-def axis_shard_map(f, mesh, in_specs, out_specs, axes):
-    """shard_map manual over ``axes``, across jax API generations:
-    ``jax.shard_map(..., axis_names=...)`` (new) vs
-    ``jax.experimental.shard_map.shard_map(..., auto=...)`` (0.4.x).
-
-    Used by the compressed cross-pod gradient mean (axes={'pod'}) and the
-    mesh-sharded paged serving step (axes={'model', ...}); the body sees
-    per-shard blocks of anything ``in_specs`` splits and stitches partial
-    results with explicit collectives (``lax.psum`` / ``stitch_heads``).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(axes))
-    from jax.experimental.shard_map import shard_map
-    # 0.4.x: the auto-axes path is unimplemented in eager mode and its
-    # SPMD lowering is unstable, so go fully manual: the body is local
-    # compute + explicit collectives over ``axes``, and with replicated
-    # in_specs the remaining axes just repeat the same deterministic
-    # work — same results.
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def stitch_heads(x, axis: str = "model", head_dim: int = 1):
     """Concat-stitch per-shard head blocks back into the full head axis
     (shard order == contiguous global head order under the column-
@@ -59,7 +36,9 @@ def stitch_heads(x, axis: str = "model", head_dim: int = 1):
 
 
 def _pod_shard_map(f, mesh, in_specs, out_specs):
-    return axis_shard_map(f, mesh, in_specs, out_specs, ("pod",))
+    """Manual over 'pod' only; data/model stay auto-partitioned."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={"pod"})
 
 
 def pod_mean_plain(grads, mesh):

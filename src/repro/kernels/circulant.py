@@ -12,9 +12,10 @@ weight traffic by m/nb·n = n, turning a memory-bound matvec into a
 compute-bound MXU op — the paper's space claim converted into arithmetic
 intensity (DESIGN.md Sec 2).
 
-Tile generation: A[i, j] = g[b(i), (j - i mod n) mod n]. Within a row tile
-the index matrix is a shifted iota; we gather from the doubled generator
-gg = [g, g] so every row is a contiguous window (monotone gather, no mod).
+Tile generation: A[i, j] = g[b(i), (j - i mod n) mod n]. Every row is
+the generator rotated by i mod n, so the tile is the broadcast generator
+with row-dependent lane rotations (the spinner kernel's ``regen_tile``;
+Mosaic lowers no gather).
 
 The pointwise nonlinearity f runs as an epilogue while the tile is still
 in VMEM (identity | relu | heaviside | exp(y - sq) | cos_sin).
@@ -27,6 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .spinner import regen_tile
 
 EPILOGUES = ("identity", "relu", "heaviside", "exp", "cos_sin")
 
@@ -43,28 +46,20 @@ def _epilogue(y, epilogue, sq):
     raise ValueError(epilogue)
 
 
-def _circ_kernel(x_ref, gg_ref, sq_ref, o_ref, *, n: int, tm: int,
-                 epilogue: str):
-    """Grid (batch_tiles, row_tiles). Regenerate (TM, n) tile rows from gg."""
+def _circ_kernel(x_ref, g_ref, sq_ref, o_ref, *, n: int, m: int, tm: int,
+                 nb: int, epilogue: str):
+    """Grid (batch_tiles, row_tiles). Regenerate (TM, n) tile rows from g."""
     j = pl.program_id(1)
-    x = x_ref[...]                                   # (TB, n)
-    gg = gg_ref[...]                                 # (nb, 2n) doubled gens
-    row0 = j * tm
-    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (tm, n), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (tm, n), 1)
-    blk = rows // n
-    off = rows % n
-    # A[i, c] = g[blk, (c - off) mod n] = gg[blk, c - off + n]
-    idx = cols - off + n                             # in [1, 2n)
-    tile = gg[blk, idx]                              # (TM, n) gather in VMEM
+    x = x_ref[...].astype(jnp.float32)               # (TB, n)
+    tile = regen_tile("circulant", g_ref, j, n=n, m=m, tm=tm, nb=nb)
     y = jax.lax.dot_general(
         x, tile, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # (TB, TM)
     if epilogue == "cos_sin":
-        o_ref[..., 0, :] = jnp.cos(y).astype(o_ref.dtype)
-        o_ref[..., 1, :] = jnp.sin(y).astype(o_ref.dtype)
+        o_ref[0] = jnp.cos(y).astype(o_ref.dtype)
+        o_ref[1] = jnp.sin(y).astype(o_ref.dtype)
     else:
-        sq = sq_ref[...][:, :1] if epilogue == "exp" else None  # (TB, 1)
+        sq = sq_ref[...].astype(jnp.float32) if epilogue == "exp" else None
         o_ref[...] = _epilogue(y, epilogue, sq).astype(o_ref.dtype)
 
 
@@ -73,9 +68,11 @@ def _circ_kernel(x_ref, gg_ref, sq_ref, o_ref, *, n: int, tm: int,
 def circulant_project_pallas(g: jax.Array, x: jax.Array, m: int,
                              epilogue: str = "identity",
                              sq: Optional[jax.Array] = None,
-                             block_b: int = 256, block_m: int = 256,
-                             interpret: bool = True) -> jax.Array:
+                             block_b: int = 256, block_m: int = 256, *,
+                             interpret: bool) -> jax.Array:
     """g: (nb, n) generators; x: (B, n) -> (B, m) (or (B, 2m) for cos_sin).
+    ``interpret`` picks the Pallas interpreter (CPU) over the compiled
+    kernel (TPU).
 
     Requires m % block_m == 0 or block_m >= m; n enters VMEM whole
     (n <= ~4096 for f32 — callers with bigger n use the jnp path).
@@ -87,15 +84,16 @@ def circulant_project_pallas(g: jax.Array, x: jax.Array, m: int,
     tb = min(block_b, bsz)
     tm = min(block_m, m)
     assert m % tm == 0, f"m={m} must tile by block_m={tm}"
-    gg = jnp.concatenate([g, g], axis=-1)            # (nb, 2n)
+    gt = g.astype(jnp.float32)[None]                 # (1, nb, n) f32 rows
     if sq is None:
         sq = jnp.zeros((bsz, 1), x.dtype)
     sq = sq.reshape(bsz, 1)
     grid = (pl.cdiv(bsz, tb), m // tm)
-    kernel = functools.partial(_circ_kernel, n=n, tm=tm, epilogue=epilogue)
+    kernel = functools.partial(_circ_kernel, n=n, m=m, tm=tm, nb=nb,
+                               epilogue=epilogue)
     if epilogue == "cos_sin":
-        out_shape = jax.ShapeDtypeStruct((bsz, 2, m), x.dtype)
-        out_specs = pl.BlockSpec((tb, 2, tm), lambda i, j: (i, 0, j))
+        out_shape = jax.ShapeDtypeStruct((2, bsz, m), x.dtype)
+        out_specs = pl.BlockSpec((2, tb, tm), lambda i, j: (0, i, j))
     else:
         out_shape = jax.ShapeDtypeStruct((bsz, m), x.dtype)
         out_specs = pl.BlockSpec((tb, tm), lambda i, j: (i, j))
@@ -104,13 +102,13 @@ def circulant_project_pallas(g: jax.Array, x: jax.Array, m: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tb, n), lambda i, j: (i, 0)),
-            pl.BlockSpec((nb, 2 * n), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, nb, n), lambda i, j: (0, 0, 0)),
             pl.BlockSpec((tb, 1), lambda i, j: (i, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(x, gg, sq)
+    )(x, gt, sq)
     if epilogue == "cos_sin":
-        y = jnp.concatenate([y[:, 0, :], y[:, 1, :]], axis=-1)
+        y = jnp.concatenate([y[0], y[1]], axis=-1)
     return y

@@ -1,11 +1,13 @@
-"""Public jit'd wrappers for the Pallas kernels with automatic fallback.
+"""Public jit'd wrappers for the Pallas kernels, one route per call.
 
-On TPU the Pallas path compiles natively; on CPU (this container) kernels
-run in ``interpret=True`` mode for correctness, and large shapes route to
-the pure-jnp reference (same semantics, faster than interpreting).
+On TPU the Pallas path compiles natively, and a call the kernel cannot
+take raises instead of leaving the device path; on CPU kernels run in
+``interpret=True`` mode for correctness, and large shapes route to the
+pure-jnp reference (same semantics, faster than interpreting).
 
 ``use_pallas``: None = auto (pallas-interpret for small, jnp for big on
-CPU; pallas-native on TPU), True/False = force.
+CPU; pallas-native on TPU), True = Pallas, False = the jnp reference on
+any backend.
 
 Routing cost model: every kernel routes on its TRUE work estimate (the
 number of MACs / elements moved, B*n*m-style), not on input sizes — see
@@ -85,9 +87,7 @@ def _route(use_pallas: Optional[bool], work_elems: int,
 
 def fwht(x: jax.Array, normalized: bool = True,
          use_pallas: Optional[bool] = None) -> jax.Array:
-    n = x.shape[-1]
-    a, b = transforms.kron_factors(n)
-    route = _route(use_pallas, x.size * (a + b))     # Kronecker-sandwich MACs
+    route = _route(use_pallas, x.size * _fwht.chunk_width(x.shape[-1]))
     if route == "ref":
         return _prof.dispatch("fwht", lambda: _ref.fwht_ref(x, normalized))
     return _prof.dispatch("fwht", lambda: _fwht.fwht_pallas(
@@ -168,12 +168,12 @@ def _spinner_vmem_bytes(kind: str, n: int, m: int, tb: int, tm: int,
                         itemsize: int = 4, seeded: bool = False) -> int:
     """Resident bytes of one spinner program (VMEM feasibility model).
 
-    Input/output tiles, generators, and d0/d1 are VMEM-resident at the
-    INPUT dtype (``itemsize``). Everything the kernel COMPUTES with is
-    f32 regardless of input dtype: the HD/sq scratch, the Kronecker
-    Hadamard factors, the sandwich intermediate, the regenerated A tile
-    (the dot consumes ``tile.astype(f32)``) and the pre-epilogue y — so
-    those terms never shrink with a narrower input dtype.
+    Input/output tiles and d0/d1 are VMEM-resident at the INPUT dtype
+    (``itemsize``). Everything the kernel COMPUTES with is f32 regardless
+    of input dtype: the HD/sq scratch, the Hadamard factor, the HD chunk
+    intermediates, the generator tables, the rotated source and the
+    regenerated A tile, and the pre-epilogue y — so those terms never
+    shrink with a narrower input dtype.
     """
     f32 = 4
     by = tb * n * itemsize    # x tile
@@ -182,19 +182,21 @@ def _spinner_vmem_bytes(kind: str, n: int, m: int, tb: int, tm: int,
     by += tb * tm * f32       # pre-epilogue y (f32)
     by += tb * tm * (2 if epilogue == "cos_sin" else 1) * itemsize  # out tile
     if use_hd:
-        a, b = transforms.kron_factors(n)
-        by += (a * a + b * b) * f32                  # hadamard factors
+        c = _fwht.chunk_width(n)
+        by += c * c * f32                            # hadamard factor
         by += 2 * n * itemsize                       # d0 / d1
-        by += tb * n * f32                           # sandwich intermediate
+        by += tb * n * f32                           # chunk intermediates
     if seeded:
         # no resident generators; the counter-PRNG's uint32 grids and
         # Box-Muller temporaries live alongside the regenerated tile
         by += 2 * tm * n * 4
         return by
     if kind in ("circulant", "skew_circulant"):
-        by += 2 * n * -(-m // n) * itemsize          # doubled generators
+        by += -(-m // n) * n * f32                   # generator blocks
+        by += tm * n * f32                           # broadcast source
     elif kind in ("toeplitz", "hankel"):
-        by += (n + m - 1) * itemsize
+        w = _spin.table_width(n, m)
+        by += w * f32 + tm * w * f32                 # table + rotated source
     # unstructured streams its (tm, n) tile — already counted above
     return by
 
@@ -226,6 +228,26 @@ def spinner_plan(kind: str, n: int, m: int, *, use_hd: bool = True,
                 break
     _plan_cache[key] = best
     return best
+
+
+def _spinner_route(kind: str, n: int, m: int, use_hd: bool,
+                   use_pallas: Optional[bool], work: int) -> str:
+    """Route of both spinner entry points. Shapes the kernel does not
+    cover (``ldr``, HD over a non-power-of-two n, oversized n or m) take
+    the jnp reference off-TPU; on TPU they raise rather than leave the
+    device path, unless the caller asked for the reference itself."""
+    route = _route(use_pallas, work, auto_interpret=False)
+    pallas_ok = (kind in _spin.PALLAS_KINDS
+                 and (not use_hd or transforms.is_pow2(n))
+                 and n <= 8192 and n + m - 1 <= (1 << 22))
+    if pallas_ok or route == "ref":
+        return route
+    if route == "native":
+        raise ValueError(
+            f"spinner kind={kind!r} n={n} m={m} use_hd={use_hd} has no "
+            "compiled TPU kernel; pass use_pallas=False to run the jnp "
+            "reference on purpose")
+    return "ref"
 
 
 def _spinner_pallas_vjp(kind: str, m: int, use_hd: bool, epilogue: str,
@@ -324,12 +346,7 @@ def spinner_project(kind: str, params: Dict[str, jax.Array], x: jax.Array,
     n = x.shape[-1]
     work = (x.size // n) * n * m
 
-    pallas_ok = (kind in _spin.PALLAS_KINDS
-                 and (not use_hd or transforms.is_pow2(n))
-                 and n <= 8192 and n + m - 1 <= (1 << 22))
-    route = _route(use_pallas, work, auto_interpret=False)
-    if not pallas_ok:
-        route = "ref"
+    route = _spinner_route(kind, n, m, use_hd, use_pallas, work)
     if route != "ref" and (block_b is None or block_m is None):
         auto_b, auto_m = spinner_plan(kind, n, m, use_hd=use_hd,
                                       epilogue=epilogue, dtype=x.dtype)
@@ -438,12 +455,7 @@ def spinner_project_seeded(kind: str, seeds: jax.Array, x: jax.Array,
     n = x.shape[-1]
     work = (x.size // n) * n * m
 
-    pallas_ok = (kind in _spin.PALLAS_KINDS
-                 and (not use_hd or transforms.is_pow2(n))
-                 and n <= 8192 and n + m - 1 <= (1 << 22))
-    route = _route(use_pallas, work, auto_interpret=False)
-    if not pallas_ok:
-        route = "ref"
+    route = _spinner_route(kind, n, m, use_hd, use_pallas, work)
     if route != "ref" and (block_b is None or block_m is None):
         auto_b, auto_m = spinner_plan(kind, n, m, use_hd=use_hd,
                                       epilogue=epilogue, dtype=x.dtype,

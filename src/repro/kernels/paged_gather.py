@@ -38,8 +38,8 @@ def _gather_dequant_kernel(tables_ref, pool_ref, scale_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_gather_pallas(pool: jax.Array, tables: jax.Array,
-                        interpret: bool = True) -> jax.Array:
+def paged_gather_pallas(pool: jax.Array, tables: jax.Array, *,
+                        interpret: bool) -> jax.Array:
     """pool: (N, P, D); tables: (R, M) int32 page ids -> (R, M*P, D).
 
     Grid (R, M): one program per (request, page slot). The scalar-prefetch
@@ -67,8 +67,8 @@ def paged_gather_pallas(pool: jax.Array, tables: jax.Array,
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
 def paged_gather_dequant_pallas(pool: jax.Array, scales: jax.Array,
                                 tables: jax.Array,
-                                out_dtype=jnp.float32,
-                                interpret: bool = True) -> jax.Array:
+                                out_dtype=jnp.float32, *,
+                                interpret: bool) -> jax.Array:
     """Fused int8 page gather + dequant.
 
     pool: (N, P, D) int8; scales: (N, P, 1) f32 per-row (per token) scales;

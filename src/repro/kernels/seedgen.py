@@ -124,7 +124,7 @@ def fold_seed(seed, data) -> jax.Array:
 def gen_tile(kind: str, seed, rows: jax.Array, cols: jax.Array, *,
              n: int, m: int, nb: int) -> jax.Array:
     """Regenerate the (tm, n) row tile A[rows, cols] straight from the
-    seed — the zero-storage analogue of ``spinner._regen_tile``.
+    seed — the zero-storage analogue of ``spinner.regen_tile``.
 
     ``rows``/``cols`` are int32 index grids (rows may exceed m on padded
     tiles; positions stay in-range by construction, the garbage rows'

@@ -13,7 +13,9 @@ _FORCED = os.environ.get("REPRO_DRYRUN_DEVICES") or \
     ("8" if ("--serve-mesh" in _sys.argv or "--serve-chaos" in _sys.argv
              or "--serve-prefix" in _sys.argv or "--serve-seeded" in _sys.argv)
      else "512")
-os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={_FORCED}"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    f"--xla_force_host_platform_device_count={_FORCED}")))
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 extract memory/cost/collective evidence for EXPERIMENTS.md.

@@ -3,6 +3,15 @@
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding constraints
+    and ``in_shardings`` of this repo are written for the partitioner to
+    complete, which explicit axes (the ``make_mesh`` default) refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -10,12 +19,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (2, 16, 16) ('pod', 'data', 'model') = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests, elastic reshapes)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return _auto_mesh(shape, axes)
 
 
 def make_serving_meshes(replicas: int, model_parallel: int = 1,

@@ -1,11 +1,13 @@
 """Serving launcher: paged continuous-batching engine, optionally
 mesh-sharded and router-replicated.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --reduced \
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b [--reduced] \
         --requests 16 --prompt-len 16 --max-new 24 [--attn srf] \
         [--policy priority] [--temperature 0.8 --top-k 40] [--legacy] \
         [--replicas 2] [--model-parallel 2] [--quantize-kv]
 
+The arch serves at its published widths (``registry.get``); ``--reduced``
+opts in to the toy-width config (``registry.reduced``) for CPU runs.
 Every registry family serves through the paged engine — dense/moe/mla,
 ssm (constant-state slots), hybrid (kv pages + ssd slots), enc-dec
 (synthetic frontend features are generated per request and encoded once
@@ -50,6 +52,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs import registry
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.models import transformer as model_lib
 from repro.obs import export as trace_export
@@ -62,7 +65,8 @@ from repro.serving import Engine, PagedConfig, Request, Router
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=registry.ARCHS)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="toy-width config (registry.reduced) for CPU runs")
     ap.add_argument("--attn", default=None, choices=[None, "full", "srf"])
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=8)
@@ -140,8 +144,10 @@ def main(argv=None):
 
     def _spans(i):
         return recorders[i] if tracing else None
+    compile_cache.enable_compile_cache()
     overrides = {"attn_impl": args.attn} if args.attn else {}
-    cfg = registry.reduced(args.arch, **overrides)
+    cfg = (registry.reduced if args.reduced else registry.get)(
+        args.arch, **overrides)
     params = model_lib.init(jax.random.PRNGKey(args.seed), cfg)
     paged = PagedConfig(quantize_kv=args.quantize_kv)
     prefix = None

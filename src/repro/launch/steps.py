@@ -166,8 +166,6 @@ def make_paged_step(cfg, mesh=None, paged=None, params_sds=None):
     if mesh is None:
         return paged_step
     from jax.sharding import PartitionSpec as P
-    from repro.distributed import collectives
-    from repro.serving import paged_cache
     from repro.serving.mesh import shard as mesh_shard
 
     tp = mesh_shard.paged_tp(cfg, mesh)
@@ -197,11 +195,12 @@ def make_paged_step(cfg, mesh=None, paged=None, params_sds=None):
                                     tp_axis="model")
         in_specs = (pspecs, poolspecs, rep, rep, rep, rep, rep)
 
-    return collectives.axis_shard_map(
-        body, mesh,
-        in_specs=in_specs,
-        out_specs=(rep, poolspecs),
-        axes=set(mesh.axis_names))
+    # Manual over every mesh axis, with varying-axes checking off: the
+    # Pallas kernels in the body declare plain output shapes (no vma),
+    # and the layer scan's carry would then mix varying and invariant
+    # types. The body is explicit per-shard compute plus stitch_heads.
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(rep, poolspecs), check_vma=False)
 
 
 def make_serve_step(cfg, greedy: bool = True, temperature: float = 1.0):

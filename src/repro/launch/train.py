@@ -14,6 +14,7 @@ import json
 import sys
 
 from repro.configs import registry
+from repro.launch import compile_cache
 from repro.launch.steps import TrainHyper
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -35,6 +36,7 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args(argv)
 
+    compile_cache.enable_compile_cache()
     overrides = {}
     if args.attn:
         overrides["attn_impl"] = args.attn

@@ -170,24 +170,6 @@ def init(rng, cfg) -> Dict:
 # segment runners (scan over stacked layers)
 # ---------------------------------------------------------------------------
 
-@jax.custom_jvp
-def _barrier(x: jax.Array) -> jax.Array:
-    """``lax.optimization_barrier`` with a differentiation rule.
-
-    The raw primitive has no JVP/transpose registration (jax 0.4.x), so any
-    ``grad`` through the scan body raises NotImplementedError. The barrier is
-    the identity on values, so the tangent passes through unbarriered — it
-    must stay a plain identity to be transposable for reverse mode.
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-@_barrier.defjvp
-def _barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return _barrier(x), t
-
-
 def _remat(cfg, fn):
     if cfg.remat == "none":
         return fn
@@ -227,14 +209,14 @@ def run_segment(stacked, cfg, kind: str, x, positions, mode: str,
             # its per-device footprint drops by the TP width. XLA inserts
             # the all-gather (pre-attention) / reduce-scatter (post-wo)
             # pair automatically from the sharding constraint.
-            x = hooks.constrain(_barrier(x), "residual")
+            x = hooks.constrain(jax.lax.optimization_barrier(x), "residual")
             aux = jnp.zeros((), jnp.float32)
             for i in range(g):
                 lp = jax.tree.map(lambda a: a[i], lp_group) if g > 1 \
                     else lp_group
                 x, a = inner(x, lp)
                 aux = aux + a
-            return _barrier(x), aux
+            return jax.lax.optimization_barrier(x), aux
 
         body = _remat(cfg, body)
         grouped = stacked if g == 1 else jax.tree.map(

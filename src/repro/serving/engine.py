@@ -28,7 +28,7 @@ import hashlib
 import itertools
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -152,6 +152,11 @@ class Engine:
     step). ``paged=PagedConfig(quantize_kv=True)`` stores KV pages as
     int8 with per-page-row scales (kv family only).
 
+    ``on_first_logits(req, row)``: called once per request when its
+    prefill finishes, with the f32 logits row (``vocab`` wide) its first
+    generated token is sampled from — for checks against a reference
+    forward pass.
+
     Enc-dec: every :class:`Request` must carry ``enc_emb`` (the frontend
     features); the engine runs the encoder exactly once per request at
     admission (batch-1, bit-identical to the legacy per-slot prefill) and
@@ -174,8 +179,10 @@ class Engine:
                  quality_every: int = 64,
                  quality_tol: float = obs_quality.DRIFT_TOL,
                  prefix: Optional[PrefixConfig] = None,
-                 spans: Optional[obs_spans.SpanRecorder] = None):
+                 spans: Optional[obs_spans.SpanRecorder] = None,
+                 on_first_logits: Optional[Callable] = None):
         self.cfg = cfg
+        self.on_first_logits = on_first_logits
         self.plan = paged_cache.plan_for(cfg)
         self.mesh = mesh
         self.paged = paged or paged_cache.PagedConfig()
@@ -672,6 +679,11 @@ class Engine:
             logits[:, :, : self.cfg.vocab],
             jnp.asarray(last_row)[:, None, None], axis=1)[:, 0]
         toks = self._sample_rows(rows, [s or work[0] for s in finishing], b)
+        if self.on_first_logits is not None:
+            host_rows = np.asarray(rows, np.float32)
+            for i, seq in enumerate(finishing):
+                if seq is not None and not seq.req.out_tokens:
+                    self.on_first_logits(seq.req, host_rows[i])
         now = time.perf_counter()
         for i, seq in enumerate(finishing):
             if seq is None:

@@ -281,6 +281,29 @@ def test_eos_on_first_token_finishes_at_prefill():
     assert eng2.metrics.value_sum("engine_decode_steps_total") == 0
 
 
+def test_on_first_logits_reports_the_first_token_row():
+    """The hook sees each request's first-token logits row exactly once;
+    its greedy argmax is the first token the engine emits."""
+    cfg = registry.reduced("qwen3-4b", n_layers=2)
+    params = T.init(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(1)
+    rows = {}
+
+    def keep(req, row):
+        assert req.uid not in rows
+        rows[req.uid] = row
+    eng = Engine(cfg, params, batch_slots=2, max_len=64, on_first_logits=keep)
+    for i in range(4):
+        eng.submit(Request(uid=i, max_new=4, prompt=rng.integers(
+            0, cfg.vocab, int(rng.integers(3, 40))).astype(np.int32)))
+    done = eng.run()
+    assert sorted(rows) == [0, 1, 2, 3]
+    for r in done:
+        assert rows[r.uid].shape == (cfg.vocab,)
+        assert rows[r.uid].dtype == np.float32
+        assert int(np.argmax(rows[r.uid])) == r.out_tokens[0]
+
+
 # ---------------------------------------------------------------------------
 # sampler
 # ---------------------------------------------------------------------------

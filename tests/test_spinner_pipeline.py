@@ -399,7 +399,7 @@ def test_per_block_row_moments_and_coherence():
 # ---------------------------------------------------------------------------
 
 def test_spinner_plan_dtype_separates_cache_entries():
-    n, m = 128, 8192
+    n, m = 256, 8192
     kw = dict(use_hd=True, epilogue="identity")
     f32 = kops.spinner_plan("circulant", n, m, dtype=jnp.float32, **kw)
     b16 = kops.spinner_plan("circulant", n, m, dtype=jnp.bfloat16, **kw)
