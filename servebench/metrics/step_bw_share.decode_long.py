@@ -1,0 +1,13 @@
+"""Least bytes of each traced decode step (``work.model``) over its
+device busy time times the peak HBM bandwidth, summed over the traced
+decode steps."""
+from servebench.metrics.common import share, traced_steps
+from servebench.work import model
+
+
+def read(run):
+    least = busy = 0.0
+    for s, (a, b) in traced_steps(run, "decode"):
+        least += model.decode_least_bytes(run.cell.config, s.rows, s.context)
+        busy += run.profile.busy_ns(a, b) * 1e-9
+    return share(least / run.peaks["hbm_bytes_per_s"], busy)
