@@ -17,9 +17,7 @@ TPU, interpret elsewhere), ``native``/``interpret`` force that exact
 mode, ``0``/``false``/``ref`` force the jnp reference.
 
 Every dispatch runs through ``repro.obs.profiling.dispatch``: the call is
-wrapped in a ``jax.named_scope`` (profiler/HLO-visible, free at runtime)
-and, after ``obs.enable_kernel_timing(registry)``, eager dispatches are
-timed to completion into ``kernel_dispatch_seconds{kernel=...}``.
+wrapped in a ``jax.named_scope`` (profiler/HLO-visible, free at runtime).
 """
 from __future__ import annotations
 

@@ -36,14 +36,16 @@ Telemetry: every engine replica and the router share ONE
 ``obs.MetricsRegistry``; ``--metrics`` prints a live one-line report
 every ``--metrics-every`` seconds plus a final latency-percentile dump,
 ``--metrics-out FILE`` additionally writes the Prometheus text
-exposition (+ ``FILE.events.jsonl``), and ``--kernel-timing`` records
-per-dispatch kernel wall times (eager dispatches only; serializing, so
-off by default). All output routes through ``obs.report.Reporter`` —
-this module is lint-pinned print-free (``tests/test_obs.py``).
+exposition (+ ``FILE.events.jsonl``). All output routes through
+``obs.report.Reporter`` — this module is lint-pinned print-free
+(``tests/test_obs.py``).
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import glob
+import os
 import sys
 import time
 
@@ -55,11 +57,29 @@ from repro.configs import registry
 from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.models import transformer as model_lib
+from repro.obs import devtrace
 from repro.obs import export as trace_export
 from repro.obs import quality as quality_lib
 from repro.obs import spans as spans_lib
 from repro.obs.report import Reporter
 from repro.serving import Engine, PagedConfig, Request, Router
+
+
+def _report_device_trace(rep: Reporter, trace_dir: str) -> None:
+    """The newest trace under ``trace_dir``, read against the engine's
+    spans: each decode step's device ms by named scope (medians) and the
+    five longest device-idle gaps inside engine steps."""
+    found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if not found:
+        rep.line(f"[device-trace] no trace under {trace_dir}")
+        return
+    tr = devtrace.read(max(found, key=os.path.getmtime))
+    parts = devtrace.step_parts(tr, "decode_step")
+    rep.line("[device-trace] decode step ms (median): " + " ".join(
+        f"{k}={v:.3f}" for k, v in parts.items()))
+    for g in devtrace.idle_gaps(tr, "engine_step", top=5):
+        rep.line(f"[device-trace] idle {g['ms']:.3f} ms in {g['leaf']}")
 
 
 def main(argv=None):
@@ -117,9 +137,6 @@ def main(argv=None):
     ap.add_argument("--shared-prefix", type=int, default=0,
                     help="synthetic prompts share their first N tokens "
                          "(workload shaping for --prefix-cache demos)")
-    ap.add_argument("--kernel-timing", action="store_true",
-                    help="record per-dispatch kernel wall times (eager "
-                         "dispatches only; serializes the device pipeline)")
     ap.add_argument("--quality-every", type=int, default=64,
                     help="decode steps between SRF row-gaussianity quality "
                          "probes (srf_row_* gauges; 0 disables)")
@@ -131,14 +148,17 @@ def main(argv=None):
                     help="record span timelines on every replica and the "
                          "router, write a merged Chrome-trace JSON here "
                          "at exit (load in Perfetto / chrome://tracing)")
+    ap.add_argument("--device-trace", default=None, metavar="DIR",
+                    help="run the serving loop under jax.profiler, writing "
+                         "the trace under DIR, and print the decode step's "
+                         "device time by named scope and the longest "
+                         "device-idle gaps by span (obs/devtrace.py)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     rep = Reporter()
     metrics = obs.MetricsRegistry()
-    if args.kernel_timing:
-        obs.enable_kernel_timing(metrics)
-    tracing = args.trace_out is not None and not args.legacy
+    tracing = (args.trace_out or args.device_trace) and not args.legacy
     recorders = [spans_lib.SpanRecorder(replica=i)
                  for i in range(max(args.replicas, 1))] if tracing else []
 
@@ -214,7 +234,9 @@ def main(argv=None):
                            enc_emb=enc, deadline=args.deadline))
     on_step = (rep.periodic(metrics, every_s=args.metrics_every)
                if args.metrics and not args.legacy else None)
-    done = (eng.run() if args.legacy else eng.run(on_step=on_step))
+    with (jax.profiler.trace(args.device_trace) if args.device_trace
+          else contextlib.nullcontext()):
+        done = (eng.run() if args.legacy else eng.run(on_step=on_step))
     dt = time.perf_counter() - t0
     tok = sum(len(r.out_tokens) for r in done)
     engine = ("legacy" if args.legacy else
@@ -241,21 +263,15 @@ def main(argv=None):
         rep.line(f"  req{r.uid}: ttft={ttft} out={r.out_tokens[:8]}...")
     if args.metrics or args.metrics_out:
         rep.final(metrics, done, dump_path=args.metrics_out)
-    if tracing:
+    if tracing and args.device_trace:
+        _report_device_trace(rep, args.device_trace)
+    if tracing and args.trace_out:
         n = trace_export.dump_chrome_trace(args.trace_out, recorders)
         spans = sum(len(r) for r in recorders)
         dropped = sum(r.dropped for r in recorders)
         rep.line(f"[trace] {args.trace_out}: {n} events from {spans} "
                  f"spans across {len(recorders)} timelines"
                  + (f" ({dropped} dropped)" if dropped else ""))
-    if args.kernel_timing and not metrics.snapshot()["histograms"].get(
-            "kernel_dispatch_seconds"):
-        rep.line("[metrics] kernel-timing: no eager dispatches recorded — "
-                 "the serving loop runs under jit, where timed dispatches "
-                 "are skipped by design; named_scope annotations still "
-                 "land in profiler timelines. Sample "
-                 "kernel_dispatch_seconds via direct ops calls or "
-                 "benchmarks instead.")
     return 0
 
 

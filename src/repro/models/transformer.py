@@ -411,13 +411,16 @@ def paged_step(params, cfg, pools: Dict, tokens: jax.Array,
                                        lpp, lsp, tables, slots, memory,
                                        tp_axis, embed_seeds)
             return y, (npp, nsp)
-        x, (np_, ns_) = jax.lax.scan(body, x, (seg_params, pseg, sseg))
+        with jax.named_scope("layers"):
+            x, (np_, ns_) = jax.lax.scan(body, x, (seg_params, pseg, sseg))
         new_paged.append(np_)
         new_slot.append(ns_)
     out_pools = {"paged": new_paged, "slot": new_slot}
     if mem_pool is not None:
         out_pools["memory"] = mem_pool        # read-only: pass through
-    return _logits(params, cfg, x), out_pools
+    with jax.named_scope("head"):
+        logits = _logits(params, cfg, x)
+    return logits, out_pools
 
 
 def _paged_layer(p, cfg, kind: str, x, positions, q_valid, lpaged, lslot,
@@ -425,48 +428,60 @@ def _paged_layer(p, cfg, kind: str, x, positions, q_valid, lpaged, lslot,
                  embed_seeds: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, Optional[Dict], Optional[Dict]]:
     """Single-layer paged step (mirrors ``layer_apply`` for serving).
-    -> (x, new_paged_pools, new_slot_pools), each keyed by component."""
-    h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
+    -> (x, new_paged_pools, new_slot_pools), each keyed by component.
+
+    Named scopes (``attn``, ``ssm``, ``mlp``; each with its norm and
+    residual) let a device trace split the layer's time by part."""
     if kind == "ssm":
         if tp_axis is not None:     # ssd pools always replicate (shard.py)
             raise ValueError("tp_axis is not supported for pure ssm stacks")
-        y, new_ssm = ssm.paged_ssm_step(p["ssm"], cfg, h, q_valid,
-                                        lslot["ssm"], slots)
-        return x + y, None, {"ssm": new_ssm}
+        with jax.named_scope("ssm"):
+            h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
+            y, new_ssm = ssm.paged_ssm_step(p["ssm"], cfg, h, q_valid,
+                                            lslot["ssm"], slots)
+            return x + y, None, {"ssm": new_ssm}
     attn_in_slot = cfg.attn_impl == "srf"   # srf state is a constant slot
     ctx = {"pool": (lslot if attn_in_slot else lpaged)["attn"],
            "tables": tables, "slots": slots, "q_valid": q_valid,
            "tp_axis": tp_axis}
     if embed_seeds is not None:
         ctx["embed_seeds"] = embed_seeds
-    a, new_attn = attention.attention(p["attn"], cfg, h, positions, "paged",
-                                      ctx)
+    with jax.named_scope("attn"):
+        h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        a, new_attn = attention.attention(p["attn"], cfg, h, positions,
+                                          "paged", ctx)
     if kind == "hybrid":
-        s, new_ssm = ssm.paged_ssm_step(p["ssm"], cfg, h, q_valid,
-                                        lslot["ssm"], slots)
-        fused = 0.5 * (layers.rmsnorm(p["fuse_na"], a, cfg.norm_eps)
-                       + layers.rmsnorm(p["fuse_ns"], s, cfg.norm_eps))
-        x = x + fused
-        x = x + layers.mlp(p["mlp"], layers.rmsnorm(p["ln2"], x, cfg.norm_eps))
+        with jax.named_scope("ssm"):
+            s, new_ssm = ssm.paged_ssm_step(p["ssm"], cfg, h, q_valid,
+                                            lslot["ssm"], slots)
+            fused = 0.5 * (layers.rmsnorm(p["fuse_na"], a, cfg.norm_eps)
+                           + layers.rmsnorm(p["fuse_ns"], s, cfg.norm_eps))
+            x = x + fused
+        with jax.named_scope("mlp"):
+            x = x + layers.mlp(p["mlp"],
+                               layers.rmsnorm(p["ln2"], x, cfg.norm_eps))
         new_s = {"ssm": new_ssm}
         if attn_in_slot:
             new_s["attn"] = new_attn
             return x, None, new_s
         return x, {"attn": new_attn}, new_s
-    x = x + a
-    if kind == "dense_cross" and memory is not None:
-        x = x + attention.paged_cross_attention(
-            p["cross"], cfg, layers.rmsnorm(p["ln_x"], x, cfg.norm_eps),
-            memory, tp_axis)
-    h2 = layers.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    if kind == "moe":
-        # q_valid keeps padded chunk-tail tokens out of expert capacity:
-        # without it real tokens' slot positions (and thus drops) depend
-        # on batch padding, breaking cross-replica determinism
-        y, _ = moe.moe_apply(p["moe"], cfg, h2, valid=q_valid)
-    else:
-        y = layers.mlp(p["mlp"], h2)
-    x = x + y
+    with jax.named_scope("attn"):
+        x = x + a
+        if kind == "dense_cross" and memory is not None:
+            x = x + attention.paged_cross_attention(
+                p["cross"], cfg, layers.rmsnorm(p["ln_x"], x, cfg.norm_eps),
+                memory, tp_axis)
+    with jax.named_scope("mlp"):
+        h2 = layers.rmsnorm(p["ln2"], x, cfg.norm_eps)
+        if kind == "moe":
+            # q_valid keeps padded chunk-tail tokens out of expert
+            # capacity: without it real tokens' slot positions (and thus
+            # drops) depend on batch padding, breaking cross-replica
+            # determinism
+            y, _ = moe.moe_apply(p["moe"], cfg, h2, valid=q_valid)
+        else:
+            y = layers.mlp(p["mlp"], h2)
+        x = x + y
     if attn_in_slot:
         return x, None, {"attn": new_attn}
     return x, {"attn": new_attn}, None

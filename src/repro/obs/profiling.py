@@ -1,46 +1,18 @@
 """Kernel profiling hooks for ``kernels/ops.py`` dispatch sites.
 
-Two layers, both zero-cost on the jit'd serving hot path:
-
-* :func:`annotate` / :func:`dispatch` wrap every kernel call in a
-  ``jax.named_scope`` so the op shows up named in HLO dumps, profiler
-  timelines (``jax.profiler.trace``) and ``jax.debug`` output. Scopes
-  are trace-time only — compiled programs pay nothing.
-* **Opt-in per-dispatch timing**: after :func:`enable_kernel_timing`,
-  every *eager* kernel dispatch is timed to completion
-  (``block_until_ready``) and recorded into the registry's
-  ``kernel_dispatch_seconds{kernel=...}`` histogram. Calls under a jit
-  trace are detected (tracer leaves) and skipped — a Python timer
-  around an abstract trace is meaningless, and blocking inside a trace
-  would be wrong. This is a debugging/bench instrument: forcing a sync
-  per dispatch serializes the device pipeline, so it stays off unless
-  explicitly enabled (see serving/README.md for overhead expectations).
+:func:`annotate` / :func:`dispatch` wrap every kernel call in a
+``jax.named_scope``, so the kernel's operations carry its name in their
+HLO ``op_name`` and show up named in HLO dumps and profiler timelines
+(``jax.profiler.trace``; ``obs.devtrace`` reads them). Scopes are
+trace-time only: compiled programs pay nothing. Device time per kernel is
+read from a profiler trace, not timed around the call.
 """
 from __future__ import annotations
 
-import time
 from contextlib import contextmanager
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
-
-_timing_registry = None                    # None = timing off
-
-
-def enable_kernel_timing(registry) -> None:
-    """Route per-dispatch timings into ``registry`` (a
-    ``MetricsRegistry``). Eager dispatches only; jit traces skip."""
-    global _timing_registry
-    _timing_registry = registry
-
-
-def disable_kernel_timing() -> None:
-    global _timing_registry
-    _timing_registry = None
-
-
-def kernel_timing_enabled() -> bool:
-    return _timing_registry is not None
 
 
 @contextmanager
@@ -50,30 +22,8 @@ def annotate(name: str):
         yield
 
 
-def _has_tracer(tree) -> bool:
-    return any(isinstance(leaf, jax.core.Tracer)
-               for leaf in jax.tree_util.tree_leaves(tree))
-
-
-def dispatch(name: str, fn: Callable[[], object],
-             registry: Optional[object] = None):
-    """Run one kernel dispatch under ``jax.named_scope(name)``; when
-    timing is enabled and the call is eager (no tracer in the result),
-    block until the result is ready and record the wall time.
-
-    ``fn`` is a zero-arg closure so the timer brackets the actual
-    dispatch, not argument preparation in the caller.
-    """
-    reg = registry if registry is not None else _timing_registry
-    timing = reg is not None
-    t0 = time.perf_counter() if timing else 0.0
+def dispatch(name: str, fn: Callable[[], object]):
+    """Run one kernel dispatch under ``jax.named_scope(name)``. ``fn`` is
+    a zero-arg closure."""
     with jax.named_scope(name):
-        out = fn()
-    if timing and not _has_tracer(out):
-        jax.block_until_ready(out)
-        reg.histogram(
-            "kernel_dispatch_seconds",
-            "eager kernel dispatch wall time (opt-in, serializing)",
-            ("kernel",),
-        ).labels(kernel=name).observe(time.perf_counter() - t0)
-    return out
+        return fn()

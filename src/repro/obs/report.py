@@ -140,11 +140,6 @@ class Reporter:
         qual = registry.snapshot()["gauges"].get("srf_quality", {})
         if qual:
             self.line(f"[metrics] srf_quality {qual}")
-        kern = registry.snapshot()["histograms"].get(
-            "kernel_dispatch_seconds", {})
-        for lbl, cs in sorted(kern.items()):
-            self.line(f"[metrics] kernel {lbl} n={cs['count']} "
-                      f"mean_ms={_fmt_ms(cs['sum'] / max(1, cs['count']))}")
         if dump_path:
             with open(dump_path, "w") as f:
                 f.write(registry.prometheus_text())

@@ -17,18 +17,36 @@ reads per step; spans never touch it). All recorders in one process
 share the perf_counter epoch, which is what lets ``obs.export`` merge
 multi-replica timelines onto one axis.
 
+An enabled recorder also puts every ``begin``/``end`` span on the device
+trace's clock: it opens a ``jax.profiler.TraceAnnotation`` named like the
+span and carrying its ``sid`` as metadata, and closes it in ``end``.
+While a ``jax.profiler`` trace runs, each span therefore appears on the
+host plane of the trace, where ``obs.devtrace`` joins it back to its
+:class:`Span` record (and its ``rows`` and other args) by ``sid``; with
+no trace running the annotation records nothing. ``instant`` marks and
+retroactive ``complete`` spans are on the ``perf_counter`` clock only.
+
+An enabled recorder also records Python garbage-collection pauses as
+``gc`` spans (args ``generation``, ``collected``), through one
+``gc.callbacks`` hook per process that is installed while any enabled
+recorder exists; a pause is recorded on every live enabled recorder.
+
 Disabled recorders (``SpanRecorder(enabled=False)``, or the shared
 module-level :data:`NOOP`) make every call a cheap early return — the
 ``span()`` context manager hands back one shared singleton, no
-allocation per call.
+allocation per call, no annotation and no gc hook.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "SpanRecorder", "NOOP"]
 
@@ -55,15 +73,16 @@ class Span:
 class _Token:
     """Mutable handle for an open span; ``tok.args[...] = v`` annotates
     the span before it closes."""
-    __slots__ = ("name", "t0", "sid", "parent", "uid", "args")
+    __slots__ = ("name", "t0", "sid", "parent", "uid", "args", "note")
 
-    def __init__(self, name, t0, sid, parent, uid, args):
+    def __init__(self, name, t0, sid, parent, uid, args, note=None):
         self.name = name
         self.t0 = t0
         self.sid = sid
         self.parent = parent
         self.uid = uid
         self.args = args
+        self.note = note             # open TraceAnnotation (profiler clock)
 
 
 # Shared token handed out by disabled recorders. Its args dict is shared
@@ -101,6 +120,39 @@ _NOOP_CTX = _NoopCtx()
 
 _SIDS = itertools.count(1)   # process-unique so merged exports never collide
 
+# Live enabled recorders, and the one gc hook they share: installed with
+# the first, removed when the last is collected.
+_GC_RECORDERS: "weakref.WeakSet[SpanRecorder]" = weakref.WeakSet()
+_GC_LIVE = [0]
+_GC_OPEN: Dict[int, _Token] = {}       # id(recorder) -> open gc span
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    if phase == "start":
+        for rec in list(_GC_RECORDERS):
+            _GC_OPEN[id(rec)] = rec.begin("gc",
+                                          generation=info["generation"])
+    else:
+        for rec in list(_GC_RECORDERS):
+            tok = _GC_OPEN.pop(id(rec), None)
+            if tok is not None:
+                tok.args["collected"] = info["collected"]
+                rec.end(tok)
+
+
+def _register_gc(rec: "SpanRecorder") -> None:
+    _GC_RECORDERS.add(rec)
+    _GC_LIVE[0] += 1
+    if _GC_LIVE[0] == 1:
+        gc.callbacks.append(_on_gc)
+    weakref.finalize(rec, _unregister_gc)
+
+
+def _unregister_gc() -> None:
+    _GC_LIVE[0] -= 1
+    if _GC_LIVE[0] == 0:
+        gc.callbacks.remove(_on_gc)
+
 
 class SpanRecorder:
     """Bounded ring of completed spans for one replica's control plane.
@@ -117,15 +169,20 @@ class SpanRecorder:
         self._ring: deque = deque(maxlen=maxlen)
         self._stack: List[_Token] = []
         self.n_recorded = 0          # total ever; drops = n_recorded - len()
+        if self.enabled:
+            _register_gc(self)
 
     # -- recording -----------------------------------------------------------
 
     def begin(self, name: str, uid: Optional[int] = None, **args) -> _Token:
         if not self.enabled:
             return _NOOP_TOKEN
-        tok = _Token(name, self._clock(), next(_SIDS),
+        sid = next(_SIDS)
+        note = TraceAnnotation(name, sid=sid)
+        note.__enter__()
+        tok = _Token(name, self._clock(), sid,
                      self._stack[-1].sid if self._stack else None,
-                     uid, dict(args) if args else {})
+                     uid, dict(args) if args else {}, note)
         self._stack.append(tok)
         return tok
 
@@ -133,6 +190,7 @@ class SpanRecorder:
         if not self.enabled or tok is _NOOP_TOKEN:
             return
         t1 = self._clock()
+        tok.note.__exit__(None, None, None)
         if self._stack and self._stack[-1] is tok:
             self._stack.pop()
         else:                        # tolerate out-of-order ends
@@ -164,7 +222,9 @@ class SpanRecorder:
                  **args) -> Optional[int]:
         """Record a span retroactively from explicit timestamps (used
         when the decision to record is only known after the fact, and by
-        golden tests that need deterministic times). Returns the sid."""
+        golden tests that need deterministic times). Returns the sid.
+        Such a span is on the ``perf_counter`` clock only: it never
+        reaches a device trace."""
         if not self.enabled:
             return None
         sid = next(_SIDS)

@@ -346,7 +346,8 @@ class Engine:
         if self._steps_since_quality < self._quality_every:
             return
         self._steps_since_quality = 0
-        stats = obs_quality.srf_quality_probe(self.cfg, self.params)
+        with self.spans.span("quality_probe"):
+            stats = obs_quality.srf_quality_probe(self.cfg, self.params)
         if not stats:
             return
         gq = self.metrics.gauge("srf_quality", "live embedding row "
@@ -442,9 +443,10 @@ class Engine:
             if seq.req.trace is not None:
                 seq.req.trace.stamp("admitted", now)
             if seq.snapshot is not None:
-                self.pools = paged_cache.restore_page_rows(
-                    self.pools, seq.table.pages, self._slot_ids(seq),
-                    seq.snapshot)
+                with self.spans.span("restore", uid=seq.req.uid):
+                    self.pools = paged_cache.restore_page_rows(
+                        self.pools, seq.table.pages, self._slot_ids(seq),
+                        seq.snapshot)
                 self.sched.restored(seq)
                 if seq.req.trace is not None:
                     seq.req.trace.stamp("restored", now)
@@ -466,9 +468,10 @@ class Engine:
             # the enc-dec memory rows are fully overwritten by the encoder
             # below, so their zeroing is skipped (one whole-pool write
             # saved per admission burst)
-            self.pools = paged_cache.zero_slot_rows(
-                self.pools, [s.slot for s in fresh],
-                zero_memory=self._encode is None)
+            with self.spans.span("zero_slot_rows", rows=len(fresh)):
+                self.pools = paged_cache.zero_slot_rows(
+                    self.pools, [s.slot for s in fresh],
+                    zero_memory=self._encode is None)
             if self._encode is not None:
                 self._write_memories(fresh)
         self._apply_forks(admitted)
@@ -477,8 +480,10 @@ class Engine:
                 # donor's constant-state snapshot at the matched token
                 # count: restoring it is what makes the shared KV pages
                 # resumable for slot-bearing plans
-                self.pools = paged_cache.restore_page_rows(
-                    self.pools, [], self._slot_ids(seq), seq.state_payload)
+                with self.spans.span("restore", uid=seq.req.uid):
+                    self.pools = paged_cache.restore_page_rows(
+                        self.pools, [], self._slot_ids(seq),
+                        seq.state_payload)
                 seq.state_payload = None
         work = self.sched.prefill_work()
         sc = self.sched_cfg
@@ -510,8 +515,9 @@ class Engine:
         forks = [s.fork for s in seqs if s.fork is not None]
         if not forks:
             return
-        self.pools = paged_cache.copy_page_rows(
-            self.pools, [f.src for f in forks], [f.dst for f in forks])
+        with self.spans.span("fork", pages=len(forks)):
+            self.pools = paged_cache.copy_page_rows(
+                self.pools, [f.src for f in forks], [f.dst for f in forks])
         self._c_cow_forks.inc(len(forks))
         self.spans.instant("cow_fork", pages=len(forks))
         for s in seqs:
@@ -610,14 +616,12 @@ class Engine:
             ps[i] = s.req.top_p
             uids[i] = s.req.uid & 0xFFFFFFFF    # negative uids (probes) wrap
             poss[i] = len(s.req.out_tokens)     # index of the token drawn
-        stok = self.spans.begin("sample")
         toks = _sample_stateless(self._base_key, jnp.asarray(uids),
                                  jnp.asarray(poss), rows,
                                  jnp.asarray(temps), jnp.asarray(ks),
                                  jnp.asarray(ps))
-        out = np.asarray(toks)
-        self.spans.end(stok)
-        return out
+        with self.spans.span("sync"):
+            return np.asarray(toks)
 
     # -- prefill ------------------------------------------------------------
 
@@ -625,88 +629,97 @@ class Engine:
         stok = self.spans.begin("prefill_step")
         sc = self.sched_cfg
         b, c, m = sc.prefill_batch, sc.prefill_chunk, sc.table_width
-        tokens = np.zeros((b, c), np.int32)
-        pos = np.zeros((b, c), np.int32)
-        qv = np.zeros((b, c), bool)
-        tables = np.zeros((b, m), np.int32)
-        slots = np.zeros((b,), np.int32)
-        last_row = np.zeros((b,), np.int32)
-        finishing: List[Optional[Sequence]] = [None] * b
-        if self._chunk is not None:
-            planned = self._chunk.plan(work, c, b)
-        else:
-            planned = [(s, min(s.prompt_len - s.prefill_pos, c))
-                       for s in work]
-        self._c_prefill_tokens.inc(sum(t for _, t in planned))
-        for i, (seq, take) in enumerate(planned):
-            self._tenant(seq.req)["prefill"].inc(take)
-            self.spans.instant("prefill_chunk", uid=seq.req.uid,
-                               tokens=take)
-            start = seq.prefill_pos
-            tr = seq.req.trace
-            if tr is not None:
-                # first chunk stamps "prefill" whether it starts at 0 or
-                # at a prefix-cache match boundary; continuations under a
-                # chunk policy stamp "chunked_prefill"
-                if tr.count("prefill") == 0:
-                    tr.stamp("prefill")
-                elif self._chunk is not None:
-                    tr.stamp("chunked_prefill")
-            if self.prefix is not None:
-                # host invariant: prefill writes only land in pages this
-                # request exclusively owns (shared prefixes are read-only)
-                cow.assert_writable(self.sched.alloc, seq.table.pages,
-                                    start, take, sc.page_size)
-            chunk = np.asarray(seq.req.prompt[start:start + take], np.int32)
-            n = len(chunk)
-            tokens[i, :n] = chunk
-            # true absolute positions (rope); the invalid tail rows are
-            # masked by q_valid, and page lookups clamp harmlessly
-            pos[i] = start + np.arange(c)
-            qv[i, :n] = True
-            tables[i] = seq.table.padded(m)
-            slots[i] = seq.slot or 0
-            seq.prefill_pos += n
-            seq.table.length = seq.prefill_pos
-            if seq.prefill_done:
-                finishing[i] = seq
-                last_row[i] = n - 1
-        es = (self._embed_seeds([s for s, _ in planned], b)
-              if self._seeded_srf else None)
-        logits, self.pools = self._run_step(tokens, pos, qv, tables, slots,
-                                            es)
-        rows = jnp.take_along_axis(
-            logits[:, :, : self.cfg.vocab],
-            jnp.asarray(last_row)[:, None, None], axis=1)[:, 0]
-        toks = self._sample_rows(rows, [s or work[0] for s in finishing], b)
-        if self.on_first_logits is not None:
-            host_rows = np.asarray(rows, np.float32)
+        with self.spans.span("build"):
+            tokens = np.zeros((b, c), np.int32)
+            pos = np.zeros((b, c), np.int32)
+            qv = np.zeros((b, c), bool)
+            tables = np.zeros((b, m), np.int32)
+            slots = np.zeros((b,), np.int32)
+            last_row = np.zeros((b,), np.int32)
+            finishing: List[Optional[Sequence]] = [None] * b
+            if self._chunk is not None:
+                planned = self._chunk.plan(work, c, b)
+            else:
+                planned = [(s, min(s.prompt_len - s.prefill_pos, c))
+                           for s in work]
+            self._c_prefill_tokens.inc(sum(t for _, t in planned))
+            for i, (seq, take) in enumerate(planned):
+                self._tenant(seq.req)["prefill"].inc(take)
+                self.spans.instant("prefill_chunk", uid=seq.req.uid,
+                                   tokens=take)
+                start = seq.prefill_pos
+                tr = seq.req.trace
+                if tr is not None:
+                    # first chunk stamps "prefill" whether it starts at 0
+                    # or at a prefix-cache match boundary; continuations
+                    # under a chunk policy stamp "chunked_prefill"
+                    if tr.count("prefill") == 0:
+                        tr.stamp("prefill")
+                    elif self._chunk is not None:
+                        tr.stamp("chunked_prefill")
+                if self.prefix is not None:
+                    # host invariant: prefill writes only land in pages
+                    # this request exclusively owns (shared prefixes are
+                    # read-only)
+                    cow.assert_writable(self.sched.alloc, seq.table.pages,
+                                        start, take, sc.page_size)
+                chunk = np.asarray(seq.req.prompt[start:start + take],
+                                   np.int32)
+                n = len(chunk)
+                tokens[i, :n] = chunk
+                # true absolute positions (rope); the invalid tail rows
+                # are masked by q_valid, and page lookups clamp harmlessly
+                pos[i] = start + np.arange(c)
+                qv[i, :n] = True
+                tables[i] = seq.table.padded(m)
+                slots[i] = seq.slot or 0
+                seq.prefill_pos += n
+                seq.table.length = seq.prefill_pos
+                if seq.prefill_done:
+                    finishing[i] = seq
+                    last_row[i] = n - 1
+            es = (self._embed_seeds([s for s, _ in planned], b)
+                  if self._seeded_srf else None)
+        with self.spans.span("dispatch"):
+            logits, self.pools = self._run_step(tokens, pos, qv, tables,
+                                                slots, es)
+        with self.spans.span("sample"):
+            rows = jnp.take_along_axis(
+                logits[:, :, : self.cfg.vocab],
+                jnp.asarray(last_row)[:, None, None], axis=1)[:, 0]
+            toks = self._sample_rows(rows, [s or work[0] for s in finishing],
+                                     b)
+        with self.spans.span("emit"):
+            if self.on_first_logits is not None:
+                host_rows = np.asarray(rows, np.float32)
+                for i, seq in enumerate(finishing):
+                    if seq is not None and not seq.req.out_tokens:
+                        self.on_first_logits(seq.req, host_rows[i])
+            now = time.perf_counter()
             for i, seq in enumerate(finishing):
-                if seq is not None and not seq.req.out_tokens:
-                    self.on_first_logits(seq.req, host_rows[i])
-        now = time.perf_counter()
-        for i, seq in enumerate(finishing):
-            if seq is None:
-                continue
-            if self.prefix is not None:
-                # cache the fully prefilled prompt BEFORE any finish path
-                # frees its pages — the cache's references keep them alive
-                self._prefix_insert(seq)
-            tok = int(toks[i])
-            seq.req.out_tokens.append(tok)
-            seq.req.t_first = now
-            if seq.req.trace is not None:
-                seq.req.trace.stamp("first_token", now)
-            self._c_tokens.inc()
-            self._tenant(seq.req)["decode"].inc()
-            # the first token can already satisfy eos/max_new — finishing
-            # here keeps max_new=1 at exactly one emitted token and frees
-            # the pages/slot a step earlier (previously such a request
-            # took one extra decode step and emitted max_new+1 tokens)
-            if tok == seq.req.eos_id or \
-                    len(seq.req.out_tokens) >= seq.req.max_new:
-                self._finish(seq, now)
-        self._flush_cache_copies()
+                if seq is None:
+                    continue
+                if self.prefix is not None:
+                    # cache the fully prefilled prompt BEFORE any finish
+                    # path frees its pages — the cache's references keep
+                    # them alive
+                    self._prefix_insert(seq)
+                tok = int(toks[i])
+                seq.req.out_tokens.append(tok)
+                seq.req.t_first = now
+                if seq.req.trace is not None:
+                    seq.req.trace.stamp("first_token", now)
+                self._c_tokens.inc()
+                self._tenant(seq.req)["decode"].inc()
+                # the first token can already satisfy eos/max_new —
+                # finishing here keeps max_new=1 at exactly one emitted
+                # token and frees the pages/slot a step earlier
+                # (previously such a request took one extra decode step
+                # and emitted max_new+1 tokens)
+                if tok == seq.req.eos_id or \
+                        len(seq.req.out_tokens) >= seq.req.max_new:
+                    self._finish(seq, now)
+            self._flush_cache_copies()
         self._c_prefill_steps.inc()
         stok.args["rows"] = len(planned)
         self.spans.end(stok)
@@ -828,52 +841,59 @@ class Engine:
     def _decode_once(self, ready: List[Sequence], stok) -> bool:
         sc = self.sched_cfg
         batch: List[Sequence] = []
-        for seq in ready:
-            if seq not in self.sched.running:
-                continue                       # evicted below us this step
-            ok, victim = self.sched.grow_for_decode(seq)
-            while not ok and victim is not None:
-                self._evict(victim)
-                batch = [s for s in batch if s is not victim]
+        with self.spans.span("grow"):
+            for seq in ready:
+                if seq not in self.sched.running:
+                    continue                   # evicted below us this step
                 ok, victim = self.sched.grow_for_decode(seq)
-            if ok:
-                batch.append(seq)
+                while not ok and victim is not None:
+                    self._evict(victim)
+                    batch = [s for s in batch if s is not victim]
+                    ok, victim = self.sched.grow_for_decode(seq)
+                if ok:
+                    batch.append(seq)
+            if batch:
+                self._apply_forks(batch)   # COW: diverging writes into
+                #                            shared pages fork first
         if not batch:
             return False
-        self._apply_forks(batch)         # COW: diverging writes into
-        #                                  shared pages fork first
         b, m = sc.max_batch, sc.table_width
-        tokens = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b, 1), np.int32)
-        qv = np.zeros((b, 1), bool)
-        tables = np.zeros((b, m), np.int32)
-        slots = np.zeros((b,), np.int32)
-        for i, seq in enumerate(batch):
-            if self.prefix is not None:
-                cow.assert_writable(self.sched.alloc, seq.table.pages,
-                                    seq.table.length, 1, sc.page_size)
-            tokens[i, 0] = seq.req.out_tokens[-1]
-            pos[i, 0] = seq.table.length
-            qv[i, 0] = True
-            tables[i] = seq.table.padded(m)
-            slots[i] = seq.slot or 0
-        es = self._embed_seeds(batch, b) if self._seeded_srf else None
-        logits, self.pools = self._run_step(tokens, pos, qv, tables, slots,
-                                            es)
-        toks = self._sample_rows(logits[:, 0, : self.cfg.vocab], batch, b)
-        now = time.perf_counter()
-        for i, seq in enumerate(batch):
-            seq.table.length += 1
-            tok = int(toks[i])
-            seq.req.out_tokens.append(tok)
-            if seq.req.trace is not None and \
-                    seq.req.trace.count("decode") == 0:
-                seq.req.trace.stamp("decode", now)
-            self._c_tokens.inc()
-            self._tenant(seq.req)["decode"].inc()
-            if tok == seq.req.eos_id or \
-                    len(seq.req.out_tokens) >= seq.req.max_new:
-                self._finish(seq, now)
+        with self.spans.span("build"):
+            tokens = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b, 1), np.int32)
+            qv = np.zeros((b, 1), bool)
+            tables = np.zeros((b, m), np.int32)
+            slots = np.zeros((b,), np.int32)
+            for i, seq in enumerate(batch):
+                if self.prefix is not None:
+                    cow.assert_writable(self.sched.alloc, seq.table.pages,
+                                        seq.table.length, 1, sc.page_size)
+                tokens[i, 0] = seq.req.out_tokens[-1]
+                pos[i, 0] = seq.table.length
+                qv[i, 0] = True
+                tables[i] = seq.table.padded(m)
+                slots[i] = seq.slot or 0
+            es = self._embed_seeds(batch, b) if self._seeded_srf else None
+        with self.spans.span("dispatch"):
+            logits, self.pools = self._run_step(tokens, pos, qv, tables,
+                                                slots, es)
+        with self.spans.span("sample"):
+            toks = self._sample_rows(logits[:, 0, : self.cfg.vocab], batch,
+                                     b)
+        with self.spans.span("emit"):
+            now = time.perf_counter()
+            for i, seq in enumerate(batch):
+                seq.table.length += 1
+                tok = int(toks[i])
+                seq.req.out_tokens.append(tok)
+                if seq.req.trace is not None and \
+                        seq.req.trace.count("decode") == 0:
+                    seq.req.trace.stamp("decode", now)
+                self._c_tokens.inc()
+                self._tenant(seq.req)["decode"].inc()
+                if tok == seq.req.eos_id or \
+                        len(seq.req.out_tokens) >= seq.req.max_new:
+                    self._finish(seq, now)
         self._c_decode_steps.inc()
         stok.args["rows"] = len(batch)
         self._maybe_sample_quality()

@@ -22,7 +22,18 @@ import jax
 import jax.numpy as jnp
 
 
+def _scoped(fn):
+    """Trace ``fn`` under ``jax.named_scope("sample")``, so the sampler's
+    device operations carry ``sample`` in their HLO ``op_name``."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.named_scope("sample"):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
 @functools.partial(jax.jit, static_argnames=())
+@_scoped
 def sample(rng: jax.Array, logits: jax.Array, temperature: jax.Array,
            top_k: jax.Array, top_p: jax.Array) -> jax.Array:
     """logits: (B, V); temperature/top_p: (B,) f32; top_k: (B,) int32
@@ -61,6 +72,7 @@ def sample(rng: jax.Array, logits: jax.Array, temperature: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=())
+@_scoped
 def sample_stateless(base_key: jax.Array, uids: jax.Array,
                      positions: jax.Array, logits: jax.Array,
                      temperature: jax.Array, top_k: jax.Array,

@@ -1,6 +1,7 @@
 """Launch plumbing: where the compile cache lands, that a TPU never takes
 the jnp reference behind the caller's back, that every Pallas entry point
 is told its route, the serving CLI, and the dry-run's XLA_FLAGS."""
+import glob
 import inspect
 import os
 import subprocess
@@ -99,6 +100,22 @@ def test_serve_cli_reduced_opt_in(monkeypatch, tmp_path):
     assert serve.main(["--arch", "qwen3-4b", "--reduced", "--requests", "2",
                        "--prompt-len", "6", "--max-new", "3",
                        "--max-len", "32"]) == 0
+
+
+def test_serve_cli_device_trace(monkeypatch, tmp_path, capsys):
+    from repro.launch import serve
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    assert serve.main(["--arch", "qwen3-4b", "--reduced", "--requests", "2",
+                       "--prompt-len", "6", "--max-new", "3",
+                       "--max-len", "32", "--device-trace",
+                       str(tmp_path / "trace")]) == 0
+    out = capsys.readouterr().out
+    assert glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                     recursive=True)
+    parts, = [ln for ln in out.splitlines() if "decode step ms" in ln]
+    assert "attn=" in parts and "idle=" in parts
+    # the CPU has no device plane: every gap is idle, named by its span
+    assert "[device-trace] idle" in out
 
 
 def test_dryrun_adds_to_xla_flags():

@@ -1,12 +1,22 @@
 """Span timelines: recorder semantics, Chrome-trace export (golden
 schema pin), multi-replica merge, and the engine/router instrumentation
 contract (spans off by default, clock reads unchanged)."""
+import gc
+import glob
 import json
 
 import numpy as np
+import pytest
 
 from repro.obs import SpanRecorder, chrome_trace, dump_chrome_trace
+from repro.obs import spans as spans_lib
 from repro.obs.spans import NOOP
+
+
+def _recorded(rec):
+    """The records of ``rec`` less ``gc`` spans: a collection may run
+    anywhere, and an enabled recorder records it."""
+    return [s for s in rec.snapshot() if s.name != "gc"]
 
 
 # ---------------------------------------------------------------------------
@@ -18,7 +28,7 @@ def test_begin_end_records_span_with_args():
     tok = rec.begin("work", uid=7, rows=3)
     tok.args["extra"] = 1
     rec.end(tok)
-    (sp,) = rec.snapshot()
+    (sp,) = _recorded(rec)
     assert sp.name == "work" and sp.uid == 7
     assert sp.args == {"rows": 3, "extra": 1}
     assert sp.t1 >= sp.t0 and sp.kind == "span"
@@ -30,7 +40,7 @@ def test_parent_links_follow_open_span_stack():
     inner = rec.begin("inner")
     rec.end(inner)
     rec.end(outer)
-    by_name = {s.name: s for s in rec.snapshot()}
+    by_name = {s.name: s for s in _recorded(rec)}
     assert by_name["outer"].parent is None
     assert by_name["inner"].parent == by_name["outer"].sid
 
@@ -39,7 +49,7 @@ def test_context_manager_and_instant():
     rec = SpanRecorder(replica=2)
     with rec.span("step", uid=1):
         rec.instant("hit", uid=1, tokens=4)
-    kinds = {s.name: s for s in rec.snapshot()}
+    kinds = {s.name: s for s in _recorded(rec)}
     assert kinds["hit"].kind == "instant"
     assert kinds["hit"].t0 == kinds["hit"].t1
     assert kinds["hit"].parent == kinds["step"].sid   # nested under step
@@ -48,8 +58,12 @@ def test_context_manager_and_instant():
 
 def test_ring_bounded_and_dropped_counter():
     rec = SpanRecorder(maxlen=4)
-    for i in range(10):
-        rec.instant(f"e{i}")
+    gc.disable()                # a gc span would take a place in the ring
+    try:
+        for i in range(10):
+            rec.instant(f"e{i}")
+    finally:
+        gc.enable()
     assert len(rec) == 4
     assert rec.dropped == 6
     assert [s.name for s in rec.snapshot()] == ["e6", "e7", "e8", "e9"]
@@ -72,7 +86,7 @@ def test_sids_unique_across_recorders():
     a, b = SpanRecorder(replica=0), SpanRecorder(replica=1)
     a.instant("x")
     b.instant("x")
-    sids = [s.sid for s in a.snapshot() + b.snapshot()]
+    sids = [s.sid for s in _recorded(a) + _recorded(b)]
     assert len(set(sids)) == 2  # process-global counter: merge-safe
 
 
@@ -201,3 +215,151 @@ def test_engine_without_spans_records_nothing():
                        max_new=2))
     eng.run()
     assert len(NOOP) == before == 0
+
+
+# ---------------------------------------------------------------------------
+# the device trace's clock and gc pauses
+# ---------------------------------------------------------------------------
+
+class _CountingNote:
+    opened = 0
+
+    def __init__(self, name, **meta):
+        self.name, self.meta = name, meta
+
+    def __enter__(self):
+        type(self).opened += 1
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_enabled_recorder_opens_one_annotation_per_span(monkeypatch):
+    monkeypatch.setattr(spans_lib, "TraceAnnotation", _CountingNote)
+    _CountingNote.opened = 0
+    rec = SpanRecorder()
+    gc.disable()
+    try:
+        tok = rec.begin("outer")
+        with rec.span("inner"):
+            rec.instant("mark")
+        rec.end(tok)
+        rec.complete("late", 1.0, 2.0)
+    finally:
+        gc.enable()
+    assert _CountingNote.opened == 2        # begin/end spans only
+    assert tok.note.name == "outer" and tok.note.meta == {"sid": tok.sid}
+
+
+def test_noop_recorder_opens_no_annotation(monkeypatch):
+    monkeypatch.setattr(spans_lib, "TraceAnnotation", _CountingNote)
+    _CountingNote.opened = 0
+    for rec in (NOOP, SpanRecorder(enabled=False)):
+        tok = rec.begin("x", rows=3)
+        rec.end(tok)
+        with rec.span("y"):
+            pass
+    assert _CountingNote.opened == 0
+    assert tok is spans_lib._NOOP_TOKEN and tok.note is None
+
+
+def test_enabled_spans_reach_the_profiler_trace_by_sid(tmp_path):
+    import jax
+    from repro.obs import devtrace
+    rec = SpanRecorder()
+    jax.numpy.ones((8,)).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("decode_step", rows=3):
+            with rec.span("sync"):
+                jax.numpy.ones((8,)).block_until_ready()
+        rec.instant("mark")
+        rec.complete("late", 1.0, 2.0)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    tr = devtrace.read(path)
+    mine = {s.sid: s for s in _recorded(rec)}
+    joined = [m for m in tr.marks if m.sid in mine]
+    assert sorted(m.name for m in joined) == ["decode_step", "sync"]
+    for m in joined:
+        assert mine[m.sid].name == m.name
+    step = next(m for m in joined if m.name == "decode_step")
+    assert mine[step.sid].args == {"rows": 3}
+    sync = next(m for m in joined if m.name == "sync")
+    assert mine[sync.sid].parent == step.sid
+    assert step.t0 <= sync.t0 and sync.t1 <= step.t1   # one clock
+    leaf = tr.innermost(0.5 * (sync.t0 + sync.t1))
+    gcs = {s.sid for s in rec.snapshot()
+           if s.name == "gc" and s.parent == sync.sid}
+    assert leaf.sid == sync.sid or leaf.sid in gcs
+
+
+def test_gc_collect_inside_an_enabled_recorder_is_one_gc_span():
+    rec = SpanRecorder()
+    with rec.span("outer"):
+        before = len([s for s in rec.snapshot() if s.name == "gc"])
+        gc.collect()
+        got = [s for s in rec.snapshot() if s.name == "gc"][before:]
+    outer, = [s for s in rec.snapshot() if s.name == "outer"]
+    g, = [s for s in got if s.args["generation"] == 2]
+    assert g.parent == outer.sid and g.t0 <= g.t1
+    assert set(g.args) == {"generation", "collected"}
+    assert len(NOOP) == 0                 # the disabled recorder records none
+
+
+def test_gc_hook_lives_as_long_as_an_enabled_recorder():
+    gc.collect()                # recorders earlier tests left in cycles
+    base = spans_lib._GC_LIVE[0]
+    rec = SpanRecorder()
+    assert spans_lib._on_gc in gc.callbacks
+    assert spans_lib._GC_LIVE[0] == base + 1
+    SpanRecorder(enabled=False)
+    assert spans_lib._GC_LIVE[0] == base + 1
+    del rec
+    gc.collect()
+    assert spans_lib._GC_LIVE[0] == base
+    assert (spans_lib._on_gc in gc.callbacks) == (base > 0)
+    assert gc.callbacks.count(spans_lib._on_gc) <= 1
+
+
+@pytest.mark.parametrize("attn", ["kv", "srf"])
+def test_engine_leaf_spans_cover_each_step(attn):
+    import jax
+    from repro.configs import registry
+    from repro.models import transformer as T
+    from repro.serving import Engine, Request
+
+    kw = {"attn_impl": "srf"} if attn == "srf" else {}
+    cfg = registry.reduced("qwen3-4b", n_layers=2, **kw)
+    params = T.init(jax.random.PRNGKey(0), cfg)
+    rec = SpanRecorder()
+    eng = Engine(cfg, params, batch_slots=2, max_len=64, spans=rec,
+                 quality_every=1)
+    for i in range(3):
+        eng.submit(Request(uid=i, prompt=np.arange(4, dtype=np.int32),
+                           max_new=4))
+    eng.run()
+    recs = _recorded(rec)
+    by_sid = {s.sid: s for s in recs}
+    kids = {}
+    for s in recs:
+        if s.parent in by_sid:
+            kids.setdefault(by_sid[s.parent].name, set()).add(s.name)
+    leaves = {"build", "dispatch", "sample", "emit"}
+    assert leaves <= kids["prefill_step"]
+    assert leaves | {"grow"} <= kids["decode_step"]
+    assert kids["sample"] == {"sync"}
+    if attn == "srf":
+        assert {"quality_probe"} <= kids["decode_step"]
+        assert {"zero_slot_rows"} <= kids["engine_step"]
+    else:
+        assert "quality_probe" not in {s.name for s in recs}
+    # leaves follow one another inside their step, in order
+    for step in (s for s in recs if s.name == "decode_step"):
+        seq = sorted((s for s in recs if s.parent == step.sid),
+                     key=lambda s: s.t0)
+        names = [s.name for s in seq if s.kind == "span"]
+        assert names[:5] == ["grow", "build", "dispatch", "sample", "emit"]
+        assert all(a.t1 <= b.t0 for a, b in zip(seq, seq[1:]))
