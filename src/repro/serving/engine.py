@@ -42,7 +42,7 @@ from repro.obs import trace as obs_trace
 
 from . import paged_cache
 from .prefix import ChunkPolicy, PrefixCache, PrefixConfig, cow
-from .sampler import sample_stateless as _sample_stateless
+from .sampler import sample_tokens as _sample_tokens
 from .scheduler import SchedConfig, Scheduler, Sequence, tenant_of
 
 
@@ -270,6 +270,10 @@ class Engine:
                                    "(prefix-cache hits skip theirs)")
         self._c_decode_steps = c("engine_decode_steps_total",
                                  "batched decode steps")
+        self._c_sample_calls = m.counter(
+            "engine_sample_calls_total", "sampler calls by program: argmax "
+            "(no row samples) or full (top-k / top-p sort)",
+            ("engine", "path"))
         self._c_preemptions = c("engine_preemptions_total",
                                 "copy-on-preempt evictions")
         self._c_expired = c("engine_expired_total",
@@ -598,28 +602,33 @@ class Engine:
 
     # -- sampling -----------------------------------------------------------
 
-    def _sample_rows(self, rows: jax.Array, seqs: List[Sequence],
+    def _sample_rows(self, rows: jax.Array, seqs: List[Optional[Sequence]],
                      n_pad: int) -> np.ndarray:
         """Stateless per-request sampling: row i's noise is keyed by
         (base_key, uid, emitted-token index), never by engine RNG state —
         the token a request samples at position p is the same whatever
         batch it lands in (and on whatever replica; FT replay re-derives
-        the identical keys from the forced-prefix high-water mark)."""
+        the identical keys from the forced-prefix high-water mark). A
+        call in which no row samples takes the argmax program
+        (``sampler.sample_tokens``); ``engine_sample_calls_total`` counts
+        the calls by path. Rows past ``seqs``, or whose entry is None,
+        are padding and draw greedily; their tokens are discarded."""
         temps = np.zeros((n_pad,), np.float32)
         ks = np.zeros((n_pad,), np.int32)
         ps = np.ones((n_pad,), np.float32)
         uids = np.zeros((n_pad,), np.uint32)
         poss = np.zeros((n_pad,), np.int32)
         for i, s in enumerate(seqs):
+            if s is None:
+                continue
             temps[i] = s.req.temperature
             ks[i] = s.req.top_k
             ps[i] = s.req.top_p
             uids[i] = s.req.uid & 0xFFFFFFFF    # negative uids (probes) wrap
             poss[i] = len(s.req.out_tokens)     # index of the token drawn
-        toks = _sample_stateless(self._base_key, jnp.asarray(uids),
-                                 jnp.asarray(poss), rows,
-                                 jnp.asarray(temps), jnp.asarray(ks),
-                                 jnp.asarray(ps))
+        toks, path = _sample_tokens(self._base_key, uids, poss, rows, temps,
+                                    ks, ps)
+        self._c_sample_calls.labels(engine=self.engine_id, path=path).inc()
         with self.spans.span("sync"):
             return np.asarray(toks)
 
@@ -687,8 +696,7 @@ class Engine:
             rows = jnp.take_along_axis(
                 logits[:, :, : self.cfg.vocab],
                 jnp.asarray(last_row)[:, None, None], axis=1)[:, 0]
-            toks = self._sample_rows(rows, [s or work[0] for s in finishing],
-                                     b)
+            toks = self._sample_rows(rows, finishing, b)
         with self.spans.span("emit"):
             if self.on_first_logits is not None:
                 host_rows = np.asarray(rows, np.float32)

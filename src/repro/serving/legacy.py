@@ -18,7 +18,7 @@ SRF state (the paper's O(m d) cache), SSD state, hybrid, enc-dec (each
 
 For simplicity slots share a common max_len; prefill runs per-request
 (batch-1) and writes into the slot. Sampling uses the SAME stateless
-per-request keys as the paged engine (``sampler.sample_stateless``:
+per-request keys as the paged engine (``sampler.sample_tokens``:
 noise from ``(base_key, uid, token index)``, never from engine state) —
 that is what lets the parity matrix pin sampled decode bit-exactly
 paged-vs-legacy, not just greedy. EOS or max_new stops.
@@ -36,7 +36,7 @@ import numpy as np
 from repro.launch import steps as step_lib
 from repro.models import transformer as model_lib
 from .engine import Request
-from .sampler import sample_stateless as _sample_stateless
+from .sampler import sample_tokens as _sample_tokens
 
 warnings.warn(
     "repro.serving.legacy is deprecated; use the paged engine "
@@ -74,14 +74,14 @@ class Engine:
         """Sample one token for ``req`` from (V,) logits; batch-1 call of
         the shared stateless sampler (bit-identical to any batched call
         with the same (uid, position) — that is the whole point)."""
-        toks = _sample_stateless(
+        toks, _ = _sample_tokens(
             self._base_key,
-            jnp.asarray([req.uid & 0xFFFFFFFF], jnp.uint32),
-            jnp.asarray([len(req.out_tokens)], jnp.int32),
+            np.asarray([req.uid & 0xFFFFFFFF], np.uint32),
+            np.asarray([len(req.out_tokens)], np.int32),
             logits[None, :],
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-            jnp.asarray([req.top_p], jnp.float32))
+            np.asarray([req.temperature], np.float32),
+            np.asarray([req.top_k], np.int32),
+            np.asarray([req.top_p], np.float32))
         return int(np.asarray(toks)[0])
 
     def _fill_slots(self, extra_batch: Optional[Dict] = None):

@@ -6,7 +6,13 @@ in a single call with no per-request branching. ``temperature <= 0``
 selects greedy argmax for that row (the engine's default, which keeps
 decoding deterministic for tests).
 
-Engines draw through :func:`sample_stateless`: the noise for row ``i``
+Engines draw through :func:`sample_tokens`, which picks the program on
+the host from the batch's temperatures: a batch with no sampled row takes
+:func:`greedy_tokens`, a plain argmax, and any other batch takes
+:func:`sample_stateless`, which sorts every row for top-k / top-p. Both
+give a greedy row the same token, ``argmax(logits.astype(f32))``.
+
+In :func:`sample_stateless` the noise for row ``i``
 is a pure function of ``(base_key, uid[i], position[i])`` — NOT of any
 engine-side RNG state, batch composition, admission order, or replica.
 That is the sampling-key contract fault-tolerant replay relies on: a
@@ -17,9 +23,11 @@ used, so temperature-sampled streams are bit-identical across rescue
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _scoped(fn):
@@ -116,3 +124,32 @@ def sample_stateless(base_key: jax.Array, uids: jax.Array,
     sampled = jnp.take_along_axis(order, pick_sorted[:, None], axis=-1)[:, 0]
     argmax = jnp.argmax(lf, axis=-1)
     return jnp.where(greedy, argmax, sampled).astype(jnp.int32)
+
+
+@jax.jit
+@_scoped
+def greedy_tokens(logits: jax.Array) -> jax.Array:
+    """logits: (B, V) -> (B,) int32 argmax per row: the token
+    :func:`sample_stateless` gives a row with ``temperature <= 0`` (the
+    same cast and the same first-index tie-break), without its sort."""
+    return jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+
+
+def sample_tokens(base_key: jax.Array, uids: np.ndarray,
+                  positions: np.ndarray, logits: jax.Array,
+                  temperature: np.ndarray, top_k: np.ndarray,
+                  top_p: np.ndarray) -> Tuple[jax.Array, str]:
+    """Draw one token per row; the sampling parameters are host (numpy)
+    arrays of shape (B,), as :func:`sample_stateless` takes them.
+
+    Returns the (B,) int32 tokens and the path taken: ``"argmax"`` when
+    no row samples (every ``temperature <= 0``, padded zero rows
+    included), else ``"full"``. The choice reads only the host arrays, so
+    it costs no device work, and each path gives every row the token
+    :func:`sample_stateless` would."""
+    if np.all(temperature <= 0.0):
+        return greedy_tokens(logits), "argmax"
+    return sample_stateless(base_key, jnp.asarray(uids),
+                            jnp.asarray(positions), logits,
+                            jnp.asarray(temperature), jnp.asarray(top_k),
+                            jnp.asarray(top_p)), "full"

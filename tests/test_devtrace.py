@@ -190,7 +190,8 @@ def test_paged_step_carries_layer_scopes(attn):
     assert scopes.count("unscoped") < 0.2 * len(scopes)
 
 
-@pytest.mark.parametrize("name", ["sample", "sample_stateless"])
+@pytest.mark.parametrize("name", ["sample", "sample_stateless",
+                                  "greedy_tokens"])
 def test_samplers_carry_the_sample_scope(name):
     from repro.serving import sampler
     b, v = 2, 16
@@ -200,6 +201,8 @@ def test_samplers_carry_the_sample_scope(name):
     lead = ((jax.random.PRNGKey(0),) if name == "sample" else
             (jax.random.PRNGKey(0), jnp.arange(b, dtype=jnp.uint32),
              jnp.zeros((b,), i32)))
+    if name == "greedy_tokens":
+        lead, knobs = (), ()
     fn = getattr(sampler, name)
     text = fn.lower(*lead, logits, *knobs).compile().as_text()
     # reducers and comparators inside sort/reduce carry a bare primitive
