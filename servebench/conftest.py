@@ -9,10 +9,11 @@ from servebench import spec
 FIXTURES = os.path.join(spec.HERE, "fixtures")
 
 
-def tiny_cell(config: str, mix: str, limits=None) -> spec.Cell:
+def tiny_cell(config: str, mix: str, like: str, limits=None) -> spec.Cell:
     """A cell of a fixture configuration (registry.reduced widths) under a
-    traffic mix cut to a few tokens, reporting the metrics of the qwen3-4b
-    cell of the same mix (the open loop: its two tails)."""
+    traffic mix cut to a few tokens, reporting the metrics of the cell
+    ``like`` of ``BENCHMARK.json`` (metrics that name no cells included;
+    the open loop adds its two tails)."""
     with open(os.path.join(FIXTURES, config + ".json")) as f:
         cfg = json.load(f)
     with open(os.path.join(spec.HERE, "traffic", mix + ".json")) as f:
@@ -23,7 +24,6 @@ def tiny_cell(config: str, mix: str, limits=None) -> spec.Cell:
         t.update(rate_per_s=4.0, horizon_s=5)
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    like = ("qwen3-4b-srf." if "srf" in config else "qwen3-4b.") + mix
     pick = lambda ms: [m for m in ms  # noqa: E731
                        if like in m.get("workloads", [like])]
     e2e = pick(bench["end_to_end"])

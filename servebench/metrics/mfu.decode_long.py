@@ -1,13 +1,15 @@
 """Model FLOP/s utilization of the whole window: the model FLOPs of every
 token processed in it (each prompt whose first token came in the window,
-and every output token emitted in it), over the window's length times the
-chip's peak bf16 FLOP/s."""
+and every output token emitted in it; the configuration's work module,
+``spec.work_module``), over the window's length times the chip's peak
+bf16 FLOP/s."""
+from servebench import spec
 from servebench.metrics.common import share
-from servebench.work import model
 
 
 def read(run):
     w, cfg = run.window, run.cell.config
+    model = spec.work_module(cfg)
     flops = 0.0
     for r in w.recs:
         p = len(r.prompt)

@@ -45,6 +45,25 @@ def _sizes(c: Dict) -> Dict:
             "tied": bool(p["tie_word_embeddings"])}
 
 
+def program_sizes(config: Dict) -> Dict:
+    """The program's ``ModelConfig`` attributes (dotted into nested ones)
+    and the values the configuration says they must hold."""
+    p = config["published"]
+    want = {"n_layers": p["num_hidden_layers"], "d_model": p["hidden_size"],
+            "n_heads": p["num_attention_heads"],
+            "n_kv_heads": p["num_key_value_heads"], "head_dim": p["head_dim"],
+            "d_ff": p["intermediate_size"], "vocab": p["vocab_size"],
+            "rope_theta": float(p["rope_theta"]), "norm_eps": p["rms_norm_eps"],
+            "tie_embeddings": p["tie_word_embeddings"], "qk_norm": True,
+            "qkv_bias": p["attention_bias"], "dtype": config["dtype"],
+            "attn_impl": config["attention"]["impl"]}
+    srf = config["attention"].get("srf")
+    if srf:
+        want.update({"srf.kind": srf["kind"], "srf.n_features":
+                     srf["n_features"], "srf.feature": srf["feature"]})
+    return want
+
+
 def weight_specs(config: Dict) -> List[WeightSpec]:
     s = _sizes(config)
     d, h, kv, hd, ff, v = s["d"], s["h"], s["kv"], s["hd"], s["ff"], s["vocab"]

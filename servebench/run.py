@@ -89,24 +89,13 @@ def enable_cache() -> str:
 
 def program_config(config: Dict):
     """The program's ModelConfig for a configuration file, checked against
-    the published sizes it must keep."""
+    the sizes its reference says it must keep (``program_sizes``)."""
     from repro.configs import registry
+    from servebench import spec
     prog = config["program"]
     get = registry.reduced if prog.get("reduced") else registry.get
     cfg = get(prog["arch"], **prog.get("overrides", {}))
-    p = config["published"]
-    want = {"n_layers": p["num_hidden_layers"], "d_model": p["hidden_size"],
-            "n_heads": p["num_attention_heads"],
-            "n_kv_heads": p["num_key_value_heads"], "head_dim": p["head_dim"],
-            "d_ff": p["intermediate_size"], "vocab": p["vocab_size"],
-            "rope_theta": float(p["rope_theta"]), "norm_eps": p["rms_norm_eps"],
-            "tie_embeddings": p["tie_word_embeddings"], "qk_norm": True,
-            "qkv_bias": p["attention_bias"], "dtype": config["dtype"],
-            "attn_impl": config["attention"]["impl"]}
-    srf = config["attention"].get("srf")
-    if srf:
-        want.update({"srf.kind": srf["kind"], "srf.n_features":
-                     srf["n_features"], "srf.feature": srf["feature"]})
+    want = spec.reference_module(config).program_sizes(config)
     for k, v in want.items():
         got = cfg
         for part in k.split("."):
@@ -121,12 +110,13 @@ def sched_config(cfg, cell):
     the configuration's slot count and pool size."""
     from repro.serving import paged_cache
     from repro.serving.engine import _default_sched
-    from servebench.work import model as work
+    from servebench import spec
     serving = cell.config["serving"]
     plan = paged_cache.plan_for(cfg)
     sched = _default_sched(cfg, serving["slots"], cell.max_len, plan, "fcfs")
     if plan.has_paged:
-        page_bytes = sched.page_size * work.kv_bytes_per_token(cell.config)
+        work = spec.work_module(cell.config)
+        page_bytes = sched.page_size * work.cache_bytes_per_token(cell.config)
         sched = dataclasses.replace(
             sched, num_pages=int(serving["pool_bytes"] // page_bytes))
     return sched

@@ -3,12 +3,38 @@
 Every piece is found by its name in ``BENCHMARK.json``, so a later change
 adds files and entries without editing any that exist:
 
-    configs/<config>.json       model sizes as published, serving sizes
+    configs/<config>.json       model sizes as published, serving sizes,
+                                the ``reference`` it names and the weight
+                                ``layout``
     traffic/<traffic>.json      parameters of one traffic mix
     references/<name>.py        a plain float32 reference the config names
+    work/<name>.py              that reference's architecture's step work
     limits/<workload>.json      the correctness limits of one cell
     metrics/<metric>.py         the reader of one per-layer metric
     peaks.json                  the chip's published peaks by device kind
+
+A configuration of a new architecture brings ``configs/<config>.json``,
+``references/<name>.py``, ``work/<name>.py``, ``limits/<workload>.json``
+and the readers of any metric it adds, and appends its entries to
+``BENCHMARK.json``. What each file must export:
+
+    references/<name>.py   program_sizes(config) -> {ModelConfig attribute
+                             (dotted into nested ones): value it must hold}
+                           weight_specs(config) -> [weights.WeightSpec]
+                           Reference(config, key, storage, fp8=False) with
+                             hidden(tokens, rows) and logits(h)
+    work/<name>.py         cache_bytes_per_token(config): bytes a token
+                             holds in the paged pool (paged caches only)
+                           token_flops(config, keys, emits),
+                           weight_bytes(config),
+                           decode_least_bytes(config, rows, context): for
+                             ``mfu.*`` and ``step_bw_share.*``
+    metrics/<metric>.py    read(run) -> float | None
+
+The ``layout`` maps each reference weight to the program's parameter path
+that holds it: one path (its leading axis holds layers 0, 1, ...), or
+``{path: first layer}`` where the program splits its layers over segments
+(``weights.program_params``).
 """
 from __future__ import annotations
 
@@ -98,6 +124,14 @@ def reference_module(config: Dict):
     name = config["reference"]
     return load_module(os.path.join(HERE, "references", name + ".py"),
                        f"servebench_reference_{name}")
+
+
+def work_module(config: Dict):
+    """The step work of the configuration's architecture, found by the
+    name of its reference."""
+    name = config["reference"]
+    return load_module(os.path.join(HERE, "work", name + ".py"),
+                       f"servebench_work_{name}")
 
 
 def metric_reader(metric_name: str):

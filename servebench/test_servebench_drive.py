@@ -8,13 +8,15 @@ import pytest
 from servebench import run
 
 SEED = 2 ** 32 + 17          # wider than 32 bits, as seeds may be
+# the benchmark configuration whose metrics each fixture reports
+LIKE = {"tiny-qwen3": "qwen3-4b", "tiny-qwen3-srf": "qwen3-4b-srf"}
 
 
 @pytest.mark.parametrize("config", ["tiny-qwen3", "tiny-qwen3-srf"])
 @pytest.mark.parametrize("mix", ["decode_long", "chat"])
 @pytest.mark.parametrize("trace", [0, 1])
 def test_rehearsal(cell_factory, config, mix, trace):
-    cell = cell_factory(config, mix)
+    cell = cell_factory(config, mix, f"{LIKE[config]}.{mix}")
     res = run.run_cell(cell, SEED, 2.0, bool(trace), allow_cpu=True)
     assert res["correct"], res["check"]
     assert res["attempted"] > 0 and res["failed"] == 0
@@ -35,7 +37,8 @@ def test_rehearsal(cell_factory, config, mix, trace):
 def test_fill_opens_the_window_with_every_client_prefilled(cell_factory,
                                                           config):
     from servebench import drive, stats, traffic
-    cell = cell_factory(config, "decode_long")
+    cell = cell_factory(config, "decode_long",
+                        f"{LIKE[config]}.decode_long")
     s = run.build(cell, SEED, False)
     run.warm_up(s)
     plan = traffic.make_plan(cell.traffic, SEED, s.sched.max_batch,

@@ -13,6 +13,8 @@ SEED = 2 ** 33 + 3
 # set between the reduced cells' readings on four seeds each (CPU): program at
 # most 0.009, float8 control at least 0.077
 LIMITS = {"logit_gap": 0.03, "short_answers": 0}
+# the benchmark configuration whose metrics each fixture reports
+LIKE = {"tiny-qwen3": "qwen3-4b", "tiny-qwen3-srf": "qwen3-4b-srf"}
 
 
 def _token_altered(eng):
@@ -38,7 +40,8 @@ def _state_unchanged(eng):
 @pytest.mark.parametrize("fault", [_token_altered, _state_unchanged],
                          ids=["token_altered", "state_unchanged"])
 def test_fault_is_not_correct(cell_factory, config, fault):
-    cell = cell_factory(config, "decode_long", LIMITS)
+    cell = cell_factory(config, "decode_long",
+                        f"{LIKE[config]}.decode_long", LIMITS)
     res = run.run_cell(cell, SEED, 2.0, False, allow_cpu=True,
                        engine_hook=fault)
     assert not res["correct"]
@@ -47,7 +50,8 @@ def test_fault_is_not_correct(cell_factory, config, fault):
 
 def test_spinner_fault_is_not_correct(cell_factory):
     # a quarter of the spinner's D0 signs flipped in the program alone
-    cell = cell_factory("tiny-qwen3-srf", "decode_long", LIMITS)
+    cell = cell_factory("tiny-qwen3-srf", "decode_long",
+                        "qwen3-4b-srf.decode_long", LIMITS)
     res = run.run_cell(cell, SEED, 2.0, False, allow_cpu=True,
                        engine_hook=calibrate.flip_d0_block(cell))
     assert not res["correct"]
@@ -56,7 +60,8 @@ def test_spinner_fault_is_not_correct(cell_factory):
 
 @pytest.mark.parametrize("config", ["tiny-qwen3", "tiny-qwen3-srf"])
 def test_float8_control_is_not_correct(cell_factory, config):
-    cell = cell_factory(config, "decode_long", LIMITS)
+    cell = cell_factory(config, "decode_long",
+                        f"{LIKE[config]}.decode_long", LIMITS)
     r = calibrate.readings_for_seed(cell, SEED, 2.0, True, False)
     assert r["logit_gap"] <= LIMITS["logit_gap"] < r["control_gap"]
     assert r["control_gap"] > 3 * r["logit_gap"]
@@ -78,7 +83,8 @@ def test_unknown_workload_no_result(capsys):
 def test_witness_reads_the_same_served_tokens(cell_factory):
     # the bfloat16-state reference reads the program's served tokens and
     # puts its own tokens first; both gaps are finite readings
-    cell = cell_factory("tiny-qwen3-srf", "decode_long", LIMITS)
+    cell = cell_factory("tiny-qwen3-srf", "decode_long",
+                        "qwen3-4b-srf.decode_long", LIMITS)
     r = calibrate.readings_for_seed(cell, SEED, 2.0, False, False,
                                     witness=True)
     assert r["tokens"] > 0 and r["fill_s"] > 0

@@ -10,7 +10,7 @@ import pytest
 from servebench import profile, spec
 from servebench.drive import StepRec, Window
 from servebench.metrics import common
-from servebench.work import kernels, model
+from servebench.work import kernels
 
 HOST, DEV, OPS = "/host:CPU", "/device:TPU:0", profile.OPS_LINE
 
@@ -87,7 +87,8 @@ def test_recorded_decode_step_bandwidth_share():
     run = _run(prof, [StepRec(0, 1, "decode", 24, 24 * 600)])
     got = spec.metric_reader("step_bw_share.decode_long").read(run)
     (a, b), = prof.steps()
-    least = model.decode_least_bytes(run.cell.config, 24, 24 * 600)
+    work = spec.work_module(run.cell.config)
+    least = work.decode_least_bytes(run.cell.config, 24, 24 * 600)
     assert got == pytest.approx(100 * least / 819e9 / (prof.busy_ns(a, b)
                                                        * 1e-9))
     idle = spec.metric_reader("idle_share.decode_long").read(run)

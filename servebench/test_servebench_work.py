@@ -7,7 +7,7 @@ import os
 import pytest
 
 from servebench import spec
-from servebench.work import kernels, model
+from servebench.work import kernels
 
 
 def _config(name):
@@ -16,6 +16,7 @@ def _config(name):
 
 
 KV, SRF = _config("qwen3-4b"), _config("qwen3-4b-srf")
+model = spec.work_module(KV)
 PEAKS = spec.load_peaks("TPU v5 lite")
 
 
@@ -37,7 +38,7 @@ def test_weight_bytes():
 
 
 def test_cache_bytes():
-    assert model.kv_bytes_per_token(KV) == 36 * 8 * 128 * 2 * 2 == 147_456
+    assert model.cache_bytes_per_token(KV) == 36 * 8 * 128 * 2 * 2 == 147_456
     assert model.srf_state_bytes(SRF) == 36 * 32 * 256 * 129 * 2 \
         == 76_087_296
 

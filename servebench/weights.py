@@ -92,36 +92,64 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
+def _placements(layout: Dict) -> Dict[str, Tuple[str, int]]:
+    """``{program path: (reference name, first layer)}``. A name maps to
+    one path, whose leading axis holds layers 0, 1, ..., or to
+    ``{path: first layer}``, one path per segment that holds it."""
+    out: Dict[str, Tuple[str, int]] = {}
+    for name, where in layout.items():
+        for path, first in ({where: 0} if isinstance(where, str)
+                            else where).items():
+            if path in out:
+                raise ValueError(f"{path} is named by both {out[path][0]} "
+                                 f"and {name}")
+            out[path] = (name, int(first))
+    return out
+
+
 def program_params(specs: List[WeightSpec], n_layers: int, shapes_tree,
-                   layout: Dict[str, str], key: jax.Array, dtype):
+                   layout: Dict, key: jax.Array, dtype):
     """The program's parameter tree (structure and leaf shapes of
-    ``shapes_tree``), every leaf the weight that ``layout`` maps to its
+    ``shapes_tree``), every leaf the weight that ``layout`` places at its
     path, zero-padded where the program pads (e.g. a padded vocabulary).
-    One jitted call on the device."""
+    A per-layer leaf holds consecutive layers from the first one its
+    placement states, as many as its leading axis; layer ``l`` is what
+    ``layer_maker`` makes for ``l``, in whichever segment holds it, and the
+    segments tile the program's ``n_layers`` layers. One jitted call on
+    the device."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes_tree)
     by_path = {_path_str(p): leaf for p, leaf in leaves}
     byname = {s.name: s for s in specs}
-    if set(layout.values()) != set(by_path):
+    where = _placements(layout)
+    if set(where) != set(by_path):
         raise ValueError(
             "weight layout does not cover the program's parameters: "
-            f"unmapped {sorted(set(by_path) - set(layout.values()))}, "
-            f"unknown {sorted(set(layout.values()) - set(by_path))}")
-    inv = {v: k for k, v in layout.items()}
+            f"unmapped {sorted(set(by_path) - set(where))}, "
+            f"unknown {sorted(set(where) - set(by_path))}")
+    segs = set()
     for path, leaf in by_path.items():
-        s = byname[inv[path]]
-        want = ((n_layers,) if s.per_layer else ()) + s.shape
+        name, first = where[path]
+        s = byname[name]
+        want = ((leaf.shape[0],) if s.per_layer else ()) + s.shape
         if len(want) != len(leaf.shape) or any(
                 w > h for w, h in zip(want, leaf.shape)):
-            raise ValueError(f"{inv[path]} {want} does not fit the "
+            raise ValueError(f"{name} {want} does not fit the "
                              f"program's {path} {leaf.shape}")
+        if s.per_layer:
+            segs.add((first, first + leaf.shape[0]))
+    segs = sorted(segs)
+    ends = [0] + [b for _, b in segs]
+    if [a for a, _ in segs] != ends[:-1] or ends[-1] != n_layers:
+        raise ValueError(f"the layout's segments {segs} do not tile the "
+                         f"program's {n_layers} layers")
 
     @jax.jit
     def build(key):
         out = []
         for p, leaf in leaves:
-            path = _path_str(p)
-            s = byname[inv[path]]
-            x = make(s, key, jnp.arange(n_layers), dtype)
+            name, first = where[_path_str(p)]
+            s = byname[name]
+            x = make(s, key, jnp.arange(first, first + leaf.shape[0]), dtype)
             pad = [(0, h - w) for w, h in zip(x.shape, leaf.shape)]
             if any(hi for _, hi in pad):
                 x = jnp.pad(x, pad)

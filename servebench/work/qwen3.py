@@ -1,5 +1,6 @@
-"""Work of a whole Qwen3-style model step, counted from the published
-sizes in a configuration file (never from the program).
+"""Work of a whole Qwen3 model step, counted from the published sizes in
+a configuration file (never from the program): the work module of the
+``qwen3`` reference (``spec.work_module``).
 
 Model FLOPs count what the mathematics needs: every weight matrix once
 per token processed, attention over the keys a token actually sees, the
@@ -69,7 +70,8 @@ def token_flops(config: Dict, keys: int, emits: bool) -> float:
     return f
 
 
-def kv_bytes_per_token(config: Dict, itemsize: int = BF16) -> int:
+def cache_bytes_per_token(config: Dict, itemsize: int = BF16) -> int:
+    """What one token holds in the paged pool: K and V of every layer."""
     p = _p(config)
     return (p["num_hidden_layers"] * p["num_key_value_heads"]
             * p["head_dim"] * 2 * itemsize)
@@ -89,4 +91,4 @@ def decode_least_bytes(config: Dict, rows: int, context: int) -> float:
     b = float(weight_bytes(config))
     if config["attention"].get("srf"):
         return b + 2.0 * rows * srf_state_bytes(config)
-    return b + (context + rows) * float(kv_bytes_per_token(config))
+    return b + (context + rows) * float(cache_bytes_per_token(config))
